@@ -18,6 +18,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.core import ColdStartEngine, LoadResult
 from repro.models import transformer
 from repro.models.api import get_config
@@ -33,6 +34,10 @@ _STORE_CACHE: Dict[Tuple[str, bool], str] = {}
 
 
 def std_parser(**defaults) -> argparse.ArgumentParser:
+    """The benchmarks' shared CLI; every benchmark entry point builds
+    its parser here, before any compile, so this is also where the
+    persistent compilation cache is turned on."""
+    compile_cache.enable()
     ap = argparse.ArgumentParser()
     ap.add_argument("--models", nargs="+",
                     default=defaults.get("models", PAPER_TRIO))

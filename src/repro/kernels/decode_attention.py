@@ -34,6 +34,21 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LANES = 128
+SUBLANES = 8
+
+
+def seq_tile(bs: int, n: int) -> int:
+    """Cache-sequence tile: ``n`` itself when it fits in ``bs``, else the
+    largest 8-aligned divisor of ``n`` not above ``bs``.  The cache is
+    the kernel's input on every step, so it is tiled, never padded."""
+    if n <= bs:
+        return n
+    for t in range(bs - bs % SUBLANES, 0, -SUBLANES):
+        if n % t == 0:
+            return t
+    raise ValueError(
+        f"no {SUBLANES}-aligned tile <= {bs} divides the cache length {n}; "
+        f"use a cache length that is a multiple of {SUBLANES}")
 
 
 def _kernel(pos_ref, q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
@@ -105,8 +120,7 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     B, H, dh = q.shape
     K, S_max = k_cache.shape[1], k_cache.shape[2]
     rep = H // K
-    bs = min(bs, S_max)
-    assert S_max % bs == 0
+    bs = seq_tile(bs, S_max)
     ns = S_max // bs
 
     qr = q.reshape(B, K, rep, dh)
@@ -136,7 +150,7 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, K, rep, dh), q.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(pos.astype(jnp.int32), qr, kc, vc)
@@ -154,16 +168,15 @@ def decode_attention_paged(q: jax.Array, k_pages: jax.Array,
     across the batch; tables: (B, NP) int32 physical page ids (logical
     sequence extent NP * pt per row); pos: (B,).  Returns (B, H, dh).
 
-    ``bs`` must divide ``pt`` so every grid block lives inside one
-    page.  Ring-window semantics are identical to the slotted kernel
-    over the logical extent.
+    The tile divides ``pt`` (:func:`seq_tile`) so every grid block
+    lives inside one page.  Ring-window semantics are identical to the
+    slotted kernel over the logical extent.
     """
     B, H, dh = q.shape
     K, pt = k_pages.shape[1], k_pages.shape[2]
     NP = tables.shape[1]
     rep = H // K
-    bs = min(bs, pt)
-    assert pt % bs == 0, (pt, bs)
+    bs = seq_tile(bs, pt)
     r = pt // bs                     # cache blocks per page
     ns = NP * r
 
@@ -203,7 +216,7 @@ def decode_attention_paged(q: jax.Array, k_pages: jax.Array,
             ],
         ),
         out_shape=jax.ShapeDtypeStruct((B, K, rep, dh), q.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(pos.astype(jnp.int32), tables.astype(jnp.int32), qr, k_pages, v_pages)

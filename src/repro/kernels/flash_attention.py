@@ -10,6 +10,10 @@ Block sizes are MXU-aligned (multiples of 128 on the contraction/lane
 dims).  Fully-masked KV blocks are skipped with ``pl.when`` — on real
 hardware this prunes ~half the work for causal prefill and all but
 ceil(window/bk)+1 blocks per q row for sliding windows.
+
+Sequence lengths need not be tile multiples: queries and keys are
+padded at the end up to whole tiles, padded keys are masked out and
+padded query rows are sliced off.
 """
 from __future__ import annotations
 
@@ -23,11 +27,16 @@ from jax.experimental.pallas import tpu as pltpu
 
 NEG_INF = -1e30
 LANES = 128
+SUBLANES = 8
+
+
+def _round_up(n: int, m: int) -> int:
+    return -(-n // m) * m
 
 
 def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
             causal: bool, window: int, bq: int, bk: int, nk: int,
-            q_offset: int, scale: float):
+            q_offset: int, kv_len: int, scale: float):
     qi = pl.program_id(2)
     ki = pl.program_id(3)
 
@@ -59,7 +68,7 @@ def _kernel(q_ref, k_ref, v_ref, o_ref, m_scr, l_scr, acc_scr, *,
 
         qpos = q_lo + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 0)
         kpos = k_lo + jax.lax.broadcasted_iota(jnp.int32, (bq, bk), 1)
-        mask = jnp.ones((bq, bk), dtype=jnp.bool_)
+        mask = kpos < kv_len                            # padded keys
         if causal:
             mask = jnp.logical_and(mask, kpos <= qpos)
             if window > 0:
@@ -97,24 +106,30 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
     """q: (B, H, S, dh); k, v: (B, K, T, dh).  Returns (B, H, S, dh).
 
     When T > S (chunked prefill against an existing prefix) queries are
-    the last S positions of the key sequence.
+    the last S positions of the key sequence.  Tiles shrink to the
+    8-aligned sequence length; S and T are padded up to whole tiles.
     """
     B, H, S, dh = q.shape
     K, T = k.shape[1], k.shape[2]
     assert H % K == 0 and k.shape == v.shape
     rep = H // K
-    bq = min(bq, S)
-    bk = min(bk, T)
-    assert S % bq == 0 and T % bk == 0, (S, bq, T, bk)
-    nq, nk = S // bq, T // bk
-    q_offset = T - S
+    q_offset = T - S                  # from the unpadded lengths
+    bq = min(bq, _round_up(S, SUBLANES))
+    bk = min(bk, _round_up(T, SUBLANES))
+    Sp, Tp = _round_up(S, bq), _round_up(T, bk)
+    if Sp > S:
+        q = jnp.pad(q, ((0, 0), (0, 0), (0, Sp - S), (0, 0)))
+    if Tp > T:
+        k = jnp.pad(k, ((0, 0), (0, 0), (0, Tp - T), (0, 0)))
+        v = jnp.pad(v, ((0, 0), (0, 0), (0, Tp - T), (0, 0)))
+    nq, nk = Sp // bq, Tp // bk
 
     grid = (B, H, nq, nk)
     kern = functools.partial(
         _kernel, causal=causal, window=window, bq=bq, bk=bk, nk=nk,
-        q_offset=q_offset, scale=1.0 / math.sqrt(dh))
+        q_offset=q_offset, kv_len=T, scale=1.0 / math.sqrt(dh))
 
-    return pl.pallas_call(
+    out = pl.pallas_call(
         kern,
         grid=grid,
         in_specs=[
@@ -125,14 +140,15 @@ def flash_attention(q: jax.Array, k: jax.Array, v: jax.Array, *,
                          lambda b, h, i, j, _rep=rep: (b, h // _rep, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, bq, dh), lambda b, h, i, j: (b, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((B, H, S, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, Sp, dh), q.dtype),
         scratch_shapes=[
             pltpu.VMEM((bq, LANES), jnp.float32),   # running max
             pltpu.VMEM((bq, LANES), jnp.float32),   # running denominator
             pltpu.VMEM((bq, dh), jnp.float32),      # output accumulator
         ],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
     )(q, k, v)
+    return out[:, :, :S] if Sp > S else out

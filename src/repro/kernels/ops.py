@@ -14,18 +14,21 @@ Every op has three implementations:
 
 Dispatch goes through one :class:`KernelRegistry`:
 
-  * **capability probing** — the first time a kernel is dispatched in
-    ``auto`` mode, the registry attempts to *lower* its Pallas callable
-    on the active backend with tiny inputs and caches the verdict.  A
-    backend that can lower the kernel (TPU) serves ``pallas``; one that
-    cannot (CPU/GPU: "Only interpret mode is supported") serves the
-    ``ref`` fallback.  The probe runs once per kernel per process —
-    never on the hot path.
+  * **capability probing** — ``auto`` serves ``pallas`` on a TPU
+    backend and ``ref`` on any other.  On a TPU, the first dispatch of
+    a kernel compiles its Pallas callable once with tiny inputs; a
+    compile failure raises with the compiler's message — a TPU never
+    silently serves the ``ref`` fallback.  The probe runs once per
+    kernel per process — never on the hot path.
   * **forcing** — ``REPRO_PALLAS`` ∈ {auto (default), pallas,
-    interpret, ref} overrides the probe, and :func:`set_mode` (the
+    interpret, ref} overrides ``auto``, and :func:`set_mode` (the
     ``--pallas`` launcher flag) overrides the env var.  Forcing
     ``pallas`` on a backend that cannot lower it fails loudly at call
     time — it never silently degrades.
+  * **meshes** — Mosaic does not partition a kernel automatically, so
+    when an operand lives on a multi-device mesh (a tensor-parallel
+    instance) the kernel runs under ``shard_map`` on every device, on
+    the whole, replicated operands (:func:`_on_devices`).
   * **block sizes** — tile shapes come from
     :func:`repro.configs.shapes.kernel_blocks` (one ``tpu`` profile,
     one ``interpret`` profile), not per-call literals.
@@ -43,6 +46,7 @@ from typing import Any, Callable, Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax.sharding import PartitionSpec
 
 from repro import analysis
 from repro.configs.shapes import kernel_blocks, wt_shard_tiles
@@ -68,20 +72,19 @@ MODES = ("auto", "pallas", "interpret", "ref")
 @dataclasses.dataclass(frozen=True)
 class KernelSpec:
     """One registered kernel: its Pallas entry point and a probe that
-    lowers it with minimal inputs (run once, verdict cached)."""
+    compiles it with minimal inputs (run once on a TPU, cached)."""
     name: str
     pallas_fn: Callable
     probe: Callable[[], Any]
 
 
 class KernelRegistry:
-    """Per-process dispatch state: forced mode + cached probe verdicts."""
+    """Per-process dispatch state: forced mode + kernels compiled."""
 
     def __init__(self):
         self._kernels: Dict[str, KernelSpec] = {}
         self._lock = analysis.make_lock("KernelRegistry._lock")
-        self._verdicts: Dict[str, bool] = {}        # guarded-by: _lock
-        self._probe_errors: Dict[str, str] = {}     # guarded-by: _lock
+        self._compiled: set = set()                 # guarded-by: _lock
         self._forced: Optional[str] = None
         # (kernel, mode) -> trace-time dispatch count: observability
         # that a given path (e.g. the serving engine's jitted step)
@@ -103,68 +106,77 @@ class KernelRegistry:
 
     def set_mode(self, mode: Optional[str]):
         """Force a dispatch mode process-wide (``--pallas`` flag).
-        ``None``/'auto' restores probe-based resolution; overrides the
+        ``None``/'auto' restores backend-based resolution; overrides the
         ``REPRO_PALLAS`` env var."""
         self._forced = None if mode is None else self._normalize(mode)
 
-    # ------------------------------------------------------------- probing
-    def pallas_supported(self, name: str) -> bool:
-        """Can this backend lower the kernel's Pallas callable?  Probed
-        once (tiny inputs, ``.lower()`` only — no execution) and
-        cached for the process lifetime."""
-        with self._lock:
-            if name not in self._verdicts:
-                try:
-                    self._kernels[name].probe()
-                    self._verdicts[name] = True
-                except Exception as e:      # lowering rejected the kernel
-                    self._verdicts[name] = False
-                    self._probe_errors[name] = f"{type(e).__name__}: {e}"
-            return self._verdicts[name]
-
     # ------------------------------------------------------------ resolve
-    def mode(self, name: str) -> str:
-        """The dispatch mode this call will take, resolving ``auto``
-        through the cached capability probe."""
-        m = self._forced or self._normalize(
+    def _requested(self) -> str:
+        return self._forced or self._normalize(
             os.environ.get("REPRO_PALLAS", "auto"))
-        if m == "auto":
-            return "pallas" if self.pallas_supported(name) else "ref"
-        return m
+
+    @staticmethod
+    def _resolve(mode: str, backend: str) -> str:
+        if mode == "auto":
+            return "pallas" if backend == "tpu" else "ref"
+        return mode
+
+    def mode(self, name: str) -> str:
+        """The dispatch mode a call of ``name`` takes now: ``auto`` is
+        ``pallas`` on a TPU backend and ``ref`` on any other."""
+        return self._resolve(self._requested(), jax.default_backend())
+
+    def check_pallas(self, name: str) -> None:
+        """Compile the kernel once on this TPU (tiny inputs, no
+        execution; cached for the process lifetime).  A failure raises
+        with the compiler's message — it is a bug, never a reason to
+        serve another implementation."""
+        with self._lock:
+            if name in self._compiled:
+                return
+            try:
+                self._kernels[name].probe()
+            except Exception as e:
+                raise RuntimeError(
+                    f"Pallas kernel {name!r} failed to compile on "
+                    f"{jax.default_backend()}: "
+                    f"{type(e).__name__}: {e}") from e
+            self._compiled.add(name)
+
+    def pallas_supported(self, name: str) -> bool:
+        """True on a TPU backend (after :meth:`check_pallas`), False on
+        any other: only a TPU runs the kernels' Mosaic lowering."""
+        if jax.default_backend() != "tpu":
+            return False
+        self.check_pallas(name)
+        return True
 
     def dispatch(self, name: str) -> str:
-        """:meth:`mode`, counted — the op wrappers call this once per
-        trace so callers can assert a path routed through a kernel."""
+        """:meth:`mode`, checked and counted — the op wrappers call this
+        once per trace, so a TPU compiles each kernel it serves before
+        baking it in, and callers can assert a path routed through a
+        kernel."""
         m = self.mode(name)
+        if m == "pallas" and jax.default_backend() == "tpu":
+            self.check_pallas(name)
         with self._lock:
             key = (name, m)
             self.dispatch_counts[key] = self.dispatch_counts.get(key, 0) + 1
         return m
 
     def fingerprint(self) -> Tuple[str, str]:
-        """Cheap dispatch-cache key: (forced-or-env mode, backend).
-        Within one process the resolved per-kernel mode is a
-        deterministic function of exactly these two, so this
-        discriminates every case the resolved modes would — WITHOUT
-        forcing capability probes (probing all kernels eagerly costs
-        ~1.7 s on CPU and would land on the first-token path)."""
-        m = self._forced or self._normalize(
-            os.environ.get("REPRO_PALLAS", "auto"))
-        return (m, jax.default_backend())
+        """Dispatch-cache key: (forced-or-env mode, backend) — the
+        resolved per-kernel mode is a function of exactly these two."""
+        return (self._requested(), jax.default_backend())
 
     def modes(self) -> Dict[str, str]:
-        """Resolved mode per kernel (probes on first call in auto)."""
+        """Resolved mode per kernel."""
         return {n: self.mode(n) for n in self._kernels}
 
     def modes_for(self, fingerprint: Tuple[str, str]) -> Dict[str, str]:
         """Resolved mode per kernel under a saved :meth:`fingerprint` —
-        exact even after a later ``set_mode``, since auto's probe-based
-        resolution is fixed per (backend, process)."""
-        mode, _backend = fingerprint
-        if mode == "auto":
-            return {n: ("pallas" if self.pallas_supported(n) else "ref")
-                    for n in self._kernels}
-        return {n: mode for n in self._kernels}
+        exact even after a later ``set_mode``."""
+        return {n: self._resolve(*fingerprint) for n in self._kernels}
 
     def dispatch_snapshot(self) -> Dict[Tuple[str, str], int]:
         """Consistent copy of :attr:`dispatch_counts` — the only
@@ -174,20 +186,31 @@ class KernelRegistry:
             return dict(self.dispatch_counts)
 
     def describe(self) -> Dict[str, Dict[str, Any]]:
-        """Per-kernel dispatch report (benchmarks / `stats()` surface)."""
-        out = {}
-        for n in sorted(self._kernels):
-            m = self.mode(n)
-            out[n] = {"mode": m,
-                      "pallas_supported": self.pallas_supported(n)}
-            with self._lock:
-                err = self._probe_errors.get(n)
-            if err is not None:
-                out[n]["probe_error"] = err
-        return out
+        """Per-kernel dispatch report: resolved mode, and whether the
+        kernel compiled on this chip (it compiles when first served).
+        Compiles nothing itself."""
+        with self._lock:
+            compiled = set(self._compiled)
+        return {n: {"mode": self.mode(n), "compiled": n in compiled}
+                for n in sorted(self._kernels)}
 
 
 registry = KernelRegistry()
+
+
+def _on_devices(kernel: Callable, *args, **static):
+    """Call a Pallas kernel on its operands' devices.  Operands on a
+    multi-device mesh (their traced sharding names it) would make the
+    partitioner split the kernel, which Mosaic refuses; instead each
+    device runs the kernel on the whole operands, replicated, as the
+    partitioner itself runs an op whose operands it does not split."""
+    meshes = [jax.typeof(a).sharding.mesh for a in args if a is not None]
+    mesh = next((m for m in meshes if not m.empty and m.size > 1), None)
+    if mesh is None:
+        return kernel(*args, **static)
+    return jax.shard_map(lambda *xs: kernel(*xs, **static), mesh=mesh,
+                         in_specs=PartitionSpec(), out_specs=PartitionSpec(),
+                         check_vma=False)(*args)
 
 
 def set_mode(mode: Optional[str]):
@@ -298,28 +321,14 @@ def flash_attention_kvmajor(q: jax.Array, k: jax.Array, v: jax.Array, *,
     qt = jnp.swapaxes(q, 1, 2)
     mode = registry.dispatch("flash_attention")
     kb = _blocks()
-    if mode == "pallas":
-        o = _flash_pallas(qt, k, v, causal=causal, window=window,
-                          bq=kb.flash_bq, bk=kb.flash_bk)
-    elif mode == "interpret":
-        # interpret path pads nothing: shrink tiles to divide S/T
-        bq = _divisor_tile(kb.flash_bq, qt.shape[2])
-        bk = _divisor_tile(kb.flash_bk, k.shape[2])
-        o = _flash_pallas(qt, k, v, causal=causal, window=window,
-                          bq=bq, bk=bk, interpret=True)
-    else:
+    if mode == "ref":
         o = _xla_flash(qt, k, v, causal=causal, window=window,
                        bk=kb.flash_ref_bk)
+    else:
+        o = _on_devices(_flash_pallas, qt, k, v, causal=causal,
+                        window=window, bq=kb.flash_bq, bk=kb.flash_bk,
+                        interpret=mode == "interpret")
     return jnp.swapaxes(o, 1, 2)
-
-
-def _divisor_tile(b: int, dim: int) -> int:
-    """Largest tile <= b that divides dim (kernels assert divisibility;
-    smoke models bring odd sequence lengths)."""
-    b = min(b, dim)
-    while dim % b:
-        b -= 1
-    return b
 
 
 def _probe_flash():
@@ -327,7 +336,7 @@ def _probe_flash():
         jnp.zeros((1, 1, 128, 128), jnp.float32),
         jnp.zeros((1, 1, 128, 128), jnp.float32),
         jnp.zeros((1, 1, 128, 128), jnp.float32),
-        causal=True, window=0, bq=128, bk=128)
+        causal=True, window=0, bq=128, bk=128).compile()
 
 
 _register("flash_attention", _flash_pallas, _probe_flash)
@@ -342,15 +351,11 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     """q: (B, H, dh); caches: (B, K, S_max, dh) kv-head-major;
     pos: (B,). -> (B, H, dh)."""
     mode = registry.dispatch("decode_attention")
-    kb = _blocks()
-    if mode == "pallas":
-        return _decode_pallas(q, k_cache, v_cache, pos, window=window,
-                              bs=kb.decode_bs)
-    if mode == "interpret":
-        bs = _divisor_tile(kb.decode_bs, k_cache.shape[2])
-        return _decode_pallas(q, k_cache, v_cache, pos, window=window,
-                              bs=bs, interpret=True)
-    return ref.decode_attention(q, k_cache, v_cache, pos, window=window)
+    if mode == "ref":
+        return ref.decode_attention(q, k_cache, v_cache, pos, window=window)
+    return _on_devices(_decode_pallas, q, k_cache, v_cache, pos,
+                       window=window, bs=_blocks().decode_bs,
+                       interpret=mode == "interpret")
 
 
 def _probe_decode():
@@ -358,7 +363,7 @@ def _probe_decode():
         jnp.zeros((1, 2, 128), jnp.float32),
         jnp.zeros((1, 1, 128, 128), jnp.float32),
         jnp.zeros((1, 1, 128, 128), jnp.float32),
-        jnp.zeros((1,), jnp.int32), window=0, bs=128)
+        jnp.zeros((1,), jnp.int32), window=0, bs=128).compile()
 
 
 _register("decode_attention", _decode_pallas, _probe_decode)
@@ -371,24 +376,16 @@ def decode_attention_paged(q: jax.Array, k_pages: jax.Array,
     (P, K, pt, dh) shared across the batch; tables (B, NP) int32 page
     ids per row; pos (B,). -> (B, H, dh).
 
-    The kernel tile must divide the page size (every cache block lives
-    inside one physical page), so both pallas and interpret modes take
-    the divisor tile of the profile's ``decode_bs``.
+    The kernel tile divides the page size (every cache block lives
+    inside one physical page).
     """
     mode = registry.dispatch("decode_attention_paged")
-    kb = _blocks()
-    pt = k_pages.shape[2]
-    if mode == "pallas":
-        return _decode_paged_pallas(q, k_pages, v_pages, tables, pos,
-                                    window=window,
-                                    bs=_divisor_tile(kb.decode_bs, pt))
-    if mode == "interpret":
-        return _decode_paged_pallas(q, k_pages, v_pages, tables, pos,
-                                    window=window,
-                                    bs=_divisor_tile(kb.decode_bs, pt),
-                                    interpret=True)
-    return ref.decode_attention_paged(q, k_pages, v_pages, tables, pos,
-                                      window=window)
+    if mode == "ref":
+        return ref.decode_attention_paged(q, k_pages, v_pages, tables, pos,
+                                          window=window)
+    return _on_devices(_decode_paged_pallas, q, k_pages, v_pages, tables,
+                       pos, window=window, bs=_blocks().decode_bs,
+                       interpret=mode == "interpret")
 
 
 def _probe_decode_paged():
@@ -397,7 +394,7 @@ def _probe_decode_paged():
         jnp.zeros((2, 1, 128, 128), jnp.float32),
         jnp.zeros((2, 1, 128, 128), jnp.float32),
         jnp.zeros((1, 2), jnp.int32),
-        jnp.zeros((1,), jnp.int32), window=0, bs=128)
+        jnp.zeros((1,), jnp.int32), window=0, bs=128).compile()
 
 
 _register("decode_attention_paged", _decode_paged_pallas,
@@ -468,12 +465,11 @@ def ssd_scan(x, dt, A, B, C, *, bc: Optional[int] = None):
         B = jnp.pad(B, ((0, 0), (0, pad), (0, 0)))
         C = jnp.pad(C, ((0, 0), (0, pad), (0, 0)))
     mode = registry.dispatch("ssd_scan")
-    if mode == "pallas":
-        y = _ssd_pallas(x, dt, A, B, C, bc=bc)
-    elif mode == "interpret":
-        y = _ssd_pallas(x, dt, A, B, C, bc=bc, interpret=True)
-    else:
+    if mode == "ref":
         y = _xla_ssd(x, dt, A, B, C, bc=bc)
+    else:
+        y = _on_devices(_ssd_pallas, x, dt, A, B, C, bc=bc,
+                        interpret=mode == "interpret")
     return y[:, :, :S] if pad else y
 
 
@@ -483,7 +479,7 @@ def _probe_ssd():
         jnp.zeros((1, 1, 128), jnp.float32),
         jnp.zeros((1,), jnp.float32),
         jnp.zeros((1, 128, 128), jnp.float32),
-        jnp.zeros((1, 128, 128), jnp.float32), bc=128)
+        jnp.zeros((1, 128, 128), jnp.float32), bc=128).compile()
 
 
 _register("ssd_scan", _ssd_pallas, _probe_ssd)
@@ -532,14 +528,15 @@ def rglru_scan(a, b, *, bc: Optional[int] = None):
     if pad:                      # trailing pad only: earlier steps unaffected
         a = jnp.pad(a, ((0, 0), (0, pad), (0, 0)))
         b = jnp.pad(b, ((0, 0), (0, pad), (0, 0)))
-    y = _rglru_pallas(a, b, bc=bc, interpret=(mode == "interpret"))
+    y = _on_devices(_rglru_pallas, a, b, bc=bc,
+                    interpret=mode == "interpret")
     return y[:, :S] if pad else y
 
 
 def _probe_rglru():
     _rglru_pallas.lower(
         jnp.zeros((1, 128, 128), jnp.float32),
-        jnp.zeros((1, 128, 128), jnp.float32), bc=128)
+        jnp.zeros((1, 128, 128), jnp.float32), bc=128).compile()
 
 
 _register("rglru_scan", _rglru_pallas, _probe_rglru)
@@ -567,12 +564,10 @@ def weight_transform(w, scale=None, *, out_dtype=jnp.bfloat16,
     bn = bn if bn is not None else kb.wt_bn
     bm = bm if bm is not None else kb.wt_bm
     mode = registry.dispatch("weight_transform")
-    if mode == "pallas":
-        return _wt_pallas(w, scale, out_dtype=out_dtype, bn=bn, bm=bm)
-    if mode == "interpret":
-        return _wt_pallas(w, scale, out_dtype=out_dtype, bn=bn, bm=bm,
-                          interpret=True)
-    return ref.weight_transform(w, scale, out_dtype)
+    if mode == "ref":
+        return ref.weight_transform(w, scale, out_dtype)
+    return _on_devices(_wt_pallas, w, scale, out_dtype=out_dtype, bn=bn,
+                       bm=bm, interpret=mode == "interpret")
 
 
 def _probe_wt():
@@ -582,7 +577,7 @@ def _probe_wt():
     _wt_pallas.lower(
         jnp.zeros((kb.wt_bn, kb.wt_bm), jnp.int8),
         jnp.zeros((kb.wt_bm,), jnp.float32),
-        out_dtype=jnp.bfloat16, bn=kb.wt_bn, bm=kb.wt_bm)
+        out_dtype=jnp.bfloat16, bn=kb.wt_bn, bm=kb.wt_bm).compile()
 
 
 _register("weight_transform", _wt_pallas, _probe_wt)
@@ -617,19 +612,11 @@ def quant_matmul(x, w, scale, *, out_dtype=None,
     lead = x.shape[:-1]
     x2 = x.reshape(-1, x.shape[-1])
     mode = registry.dispatch("quant_matmul")
-    if mode == "pallas":
-        out = _qm_pallas(x2, w, scale, out_dtype=out_dtype,
-                         bm=bm, bk=bk, bn=bn)
-    elif mode == "interpret":
-        # shrink tiles to divide each dim: no padded grid cells in the
-        # (slow) interpret loop
-        out = _qm_pallas(x2, w, scale, out_dtype=out_dtype,
-                         bm=_divisor_tile(bm, x2.shape[0]),
-                         bk=_divisor_tile(bk, w.shape[0]),
-                         bn=_divisor_tile(bn, w.shape[1]),
-                         interpret=True)
-    else:
+    if mode == "ref":
         out = ref.quant_matmul(x2, w, scale, out_dtype)
+    else:
+        out = _on_devices(_qm_pallas, x2, w, scale, out_dtype=out_dtype,
+                          bm=bm, bk=bk, bn=bn, interpret=mode == "interpret")
     return out.reshape(lead + (w.shape[1],))
 
 
@@ -639,7 +626,8 @@ def _probe_qm():
         jnp.zeros((kb.qm_bm, kb.qm_bk), jnp.float32),
         jnp.zeros((kb.qm_bk, kb.qm_bn), jnp.int8),
         jnp.zeros((kb.qm_bn,), jnp.float32),
-        out_dtype=jnp.float32, bm=kb.qm_bm, bk=kb.qm_bk, bn=kb.qm_bn)
+        out_dtype=jnp.float32, bm=kb.qm_bm, bk=kb.qm_bk,
+        bn=kb.qm_bn).compile()
 
 
 _register("quant_matmul", _qm_pallas, _probe_qm)

@@ -54,7 +54,7 @@ def rglru_scan(a: jax.Array, b: jax.Array, *, bc: int = 256,
         out_specs=pl.BlockSpec((1, bc, W), lambda i, c: (i, c, 0)),
         out_shape=jax.ShapeDtypeStruct((B, S, W), a.dtype),
         scratch_shapes=[pltpu.VMEM((1, W), jnp.float32)],
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(a, b)

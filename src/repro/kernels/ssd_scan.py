@@ -103,7 +103,7 @@ def ssd_scan(x: jax.Array, dt: jax.Array, A: jax.Array, B: jax.Array,
             scratch_shapes=[pltpu.VMEM((dp, N), jnp.float32)],
         ),
         out_shape=jax.ShapeDtypeStruct((b, nh, S, dp), x.dtype),
-        compiler_params=pltpu.TPUCompilerParams(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(A.astype(jnp.float32), x, dt, B, C)

@@ -55,7 +55,7 @@ def weight_transform(w: jax.Array, scale=None, *, out_dtype=jnp.bfloat16,
             ],
             out_specs=pl.BlockSpec((bn, bm), lambda i, j: (i, j)),
             out_shape=jax.ShapeDtypeStruct((N, M), out_dtype),
-            compiler_params=pltpu.TPUCompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")),
             interpret=interpret,
         )(wp, sp[None, :])
@@ -66,7 +66,7 @@ def weight_transform(w: jax.Array, scale=None, *, out_dtype=jnp.bfloat16,
             in_specs=[pl.BlockSpec((bn, bm), lambda i, j: (i, j))],
             out_specs=pl.BlockSpec((bn, bm), lambda i, j: (i, j)),
             out_shape=jax.ShapeDtypeStruct((N, M), out_dtype),
-            compiler_params=pltpu.TPUCompilerParams(
+            compiler_params=pltpu.CompilerParams(
                 dimension_semantics=("parallel", "parallel")),
             interpret=interpret,
         )(wp)

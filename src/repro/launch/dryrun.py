@@ -1,8 +1,11 @@
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
+os.environ["JAX_PLATFORMS"] = "cpu"
 # ^ MUST precede any jax import: jax locks the device count on first init.
 # The dry-run (and only the dry-run) builds the production meshes out of
 # 512 placeholder host devices; smoke tests and benches see 1 device.
+# It is a host-only tool: it never takes an accelerator from whatever
+# process holds it.
 
 """Multi-pod dry-run: prove the distribution config is coherent.
 
@@ -209,8 +212,6 @@ def _memory_record(compiled) -> Dict[str, Any]:
 
 def _cost_record(compiled) -> Dict[str, Any]:
     ca = compiled.cost_analysis()
-    if isinstance(ca, (list, tuple)):          # jax 0.4.x: one dict/program
-        ca = ca[0] if ca else {}
     coll = hlo_analysis.collective_bytes(compiled.as_text())
     return {"flops": float(ca.get("flops", 0.0)),
             "bytes": float(ca.get("bytes accessed", 0.0)),

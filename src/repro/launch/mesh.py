@@ -14,12 +14,14 @@ from __future__ import annotations
 from typing import Sequence
 
 import jax
+from jax.sharding import AxisType
 
 
 def _make_mesh(shape, axes) -> jax.sharding.Mesh:
-    # jax 0.4.x `make_mesh` has no ``axis_types`` parameter (it appeared
-    # in 0.5+, where Auto is also the default) — call it portably.
-    return jax.make_mesh(tuple(shape), tuple(axes))
+    # `make_mesh` defaults to Explicit axes; every sharding rule here
+    # (with_sharding_constraint, gather_rows) assumes Auto propagation.
+    return jax.make_mesh(tuple(shape), tuple(axes),
+                         axis_types=(AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> jax.sharding.Mesh:

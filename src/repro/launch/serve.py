@@ -56,6 +56,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from repro import compile_cache
 from repro.models import transformer
 from repro.models.api import get_config
 from repro.serving.api import GenerateSpec
@@ -175,6 +176,7 @@ def main(argv=None):
                     help="--autoscale: arrival rate one warm instance "
                          "is budgeted to absorb")
     args = ap.parse_args(argv)
+    compile_cache.enable()
 
     if args.pallas:
         from repro.kernels import ops

@@ -86,6 +86,7 @@ class Response:
     queue_s: float = 0.0    # admission -> service start (router queue +
                             # pool wait + instance provisioning)
     cls: RequestClass = RequestClass.INFERENCE
+    logits: Optional[Any] = None         # one-shot requests: the output
     # generation requests only (None for one-shot logits requests):
     tokens: Optional[Any] = None         # (n,) int array of emitted ids
     ttft_s: Optional[float] = None       # service start -> first token

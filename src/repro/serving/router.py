@@ -174,7 +174,8 @@ class Router:
                         timeout=self.acquire_timeout_s,
                         logical_now=req.t_logical),
                     release=pool.release,
-                    service=lambda inst: inst.invoke(req.batch))
+                    service=lambda inst: inst.invoke(req.batch),
+                    extra=lambda logits, t_arr: dict(logits=logits))
 
     def _dispatch_gen(self, req: Request, fut: "Future[Response]"):
         """Generation dispatch: a *shared* pool hold — concurrent

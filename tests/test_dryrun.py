@@ -18,7 +18,7 @@ REPO = os.path.abspath(os.path.join(os.path.dirname(__file__), ".."))
 def run_dryrun(*args, timeout=560):
     env = dict(os.environ)
     env["PYTHONPATH"] = os.path.join(REPO, "src")
-    env.pop("JAX_PLATFORMS", None)
+    env["JAX_PLATFORMS"] = "cpu"
     env.pop("XLA_FLAGS", None)
     return subprocess.run(
         [sys.executable, "-m", "repro.launch.dryrun", *args],

@@ -351,8 +351,7 @@ def test_scheduler_runs_interpret_kernels_bit_identical(dense, monkeypatch):
     kernel bodies* — the registry records the dispatches — and the
     token stream stays bit-identical to the serial reference traced
     under the same mode.  Default (and any non-TPU run): interpret
-    mode.  CI's workflow_dispatch tpu-pallas leg exports
-    REPRO_PALLAS=pallas on a TPU runner and the same assertions hold
+    mode; with REPRO_PALLAS=pallas on a TPU the same assertions hold
     against the real Mosaic lowerings."""
     import os
 
@@ -379,8 +378,9 @@ def test_scheduler_runs_interpret_kernels_bit_identical(dense, monkeypatch):
 
 
 def test_registry_auto_probes_and_forces(monkeypatch):
-    """auto resolves through the cached capability probe (ref on CPU);
-    set_mode overrides the env var; bogus modes fail loudly."""
+    """auto resolves by backend (ref off a TPU, where nothing is
+    compiled); set_mode overrides the env var; bogus modes fail
+    loudly."""
     from repro.kernels import ops
 
     monkeypatch.delenv("REPRO_PALLAS", raising=False)
@@ -390,8 +390,9 @@ def test_registry_auto_probes_and_forces(monkeypatch):
                          "rglru_scan", "weight_transform",
                          "quant_matmul"}
     if jax.default_backend() != "tpu":
-        assert all(not d["pallas_supported"] for d in desc.values())
+        assert all(not d["compiled"] for d in desc.values())
         assert all(d["mode"] == "ref" for d in desc.values())
+        assert not ops.registry.pallas_supported("flash_attention")
     monkeypatch.setenv("REPRO_PALLAS", "xla")       # legacy alias
     assert ops.registry.mode("flash_attention") == "ref"
     ops.set_mode("interpret")                       # flag beats env
@@ -403,3 +404,32 @@ def test_registry_auto_probes_and_forces(monkeypatch):
         ops.set_mode(None)
     with pytest.raises(ValueError, match="must be one of"):
         ops.set_mode("vulkan")
+
+
+def test_registry_probe_failure_on_tpu_raises(monkeypatch):
+    """On a TPU backend a kernel whose probe fails to compile raises
+    with the compiler's message at dispatch — auto never downgrades it
+    to ``ref``.  A kernel that compiles is checked once and counted."""
+    from repro.kernels import ops
+
+    def broken():
+        raise NotImplementedError("Unimplemented primitive: cumsum")
+
+    calls = []
+    reg = ops.KernelRegistry()
+    reg.register(ops.KernelSpec("broken", None, broken))
+    reg.register(ops.KernelSpec("fine", None, lambda: calls.append(1)))
+    monkeypatch.delenv("REPRO_PALLAS", raising=False)
+    monkeypatch.setattr(ops.jax, "default_backend", lambda: "tpu")
+    assert reg.mode("broken") == "pallas"
+    with pytest.raises(RuntimeError, match="cumsum"):
+        reg.dispatch("broken")
+    with pytest.raises(RuntimeError, match="'broken' failed to compile"):
+        reg.pallas_supported("broken")
+    assert reg.dispatch("fine") == "pallas"
+    assert reg.dispatch("fine") == "pallas"
+    assert calls == [1]
+    assert reg.describe() == {"broken": {"mode": "pallas",
+                                         "compiled": False},
+                              "fine": {"mode": "pallas", "compiled": True}}
+    assert reg.dispatch_snapshot() == {("fine", "pallas"): 2}

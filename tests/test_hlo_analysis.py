@@ -90,10 +90,7 @@ def test_unrolled_scan_cost_exactness():
     ws = jax.ShapeDtypeStruct((4, d, d), jnp.float32)
     analytic = 2 * 8 * d * d * 4
     def cost(compiled):
-        ca = compiled.cost_analysis()
-        if isinstance(ca, (list, tuple)):      # jax 0.4.x: one dict/program
-            ca = ca[0] if ca else {}
-        return ca
+        return compiled.cost_analysis()
 
     f_scan = jax.jit(lambda x, w: fwd(x, w, False)).lower(xs, ws).compile()
     f_unrl = jax.jit(lambda x, w: fwd(x, w, True)).lower(xs, ws).compile()

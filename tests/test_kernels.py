@@ -176,7 +176,13 @@ def test_decode_paged_ragged_tables():
     k_pages, v_pages, tables = _paged_from_slotted(kc, vc, S // pt, pt,
                                                    n_garbage=2)
     o_ref = ref.decode_attention_paged(q, k_pages, v_pages, tables, pos)
-    np.testing.assert_array_equal(np.asarray(o_ref), np.asarray(o_slot))
+    # the slotted oracle over the same extent, with different garbage in
+    # the two extra pages: exact equality means masking zeroes them
+    # exactly (the softmax reduces over the same length in both)
+    kx = jnp.concatenate([kc, arr(B, K, 2 * pt, dh)], axis=2)
+    vx = jnp.concatenate([vc, arr(B, K, 2 * pt, dh)], axis=2)
+    o_ext = ref.decode_attention(q, kx, vx, pos)
+    np.testing.assert_array_equal(np.asarray(o_ref), np.asarray(o_ext))
     o_pal = paged_pallas(q, k_pages, v_pages, tables, pos, bs=16,
                          interpret=True)
     np.testing.assert_allclose(np.asarray(o_pal), np.asarray(o_slot),

@@ -3,9 +3,9 @@ resident as :class:`~repro.quant.QuantLeaf` (no ``weight_transform`` at
 commit), forwards dispatch the fused-dequant ``quant_matmul`` kernel,
 and generation stays token-identical to the dequant-at-load reference.
 
-CI's workflow_dispatch tpu-pallas leg runs this file under
-``REPRO_PALLAS=pallas``; the default (and any non-TPU run) exercises
-interpret mode — the same kernel bodies walked by the interpreter.
+On the CPU these tests exercise interpret mode — the same kernel bodies
+walked by the interpreter; ``tests/test_tpu_compile.py`` compiles
+``quant_matmul`` and ``weight_transform`` for a TPU at real widths.
 """
 import dataclasses
 import types
