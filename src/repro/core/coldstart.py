@@ -322,20 +322,21 @@ class ColdStartEngine:
                               source=self.source if strat.decouple
                               and self.cache is not None else None,
                               plan_fn=self._plan if sharded else None)
-        trace.start()
-
-        try:
-            if not strat.pipelined:
-                result = self._load_traditional(batch, units, keys, trace,
-                                                dec, on_logits)
-            else:
-                result = self._load_pipelined(batch, units, keys, trace, dec,
-                                              scheduler, state, on_logits)
-        finally:
-            # shutdown now guards shared-cache invariants (pin sweep +
-            # unregister_load), so it must run on the failure path too
-            dec.shutdown()
-        trace.finish()
+        with jax.profiler.TraceAnnotation("coldstart.load"):
+            trace.start()
+            try:
+                if not strat.pipelined:
+                    result = self._load_traditional(
+                        batch, units, keys, trace, dec, on_logits)
+                else:
+                    result = self._load_pipelined(
+                        batch, units, keys, trace, dec, scheduler, state,
+                        on_logits)
+            finally:
+                # shutdown now guards shared-cache invariants (pin sweep +
+                # unregister_load), so it must run on the failure path too
+                dec.shutdown()
+            trace.finish()
         self._record_load(trace)
         return result
 
@@ -367,18 +368,16 @@ class ColdStartEngine:
         applied = {}
         sharded = {}
         for u in units:                                  # monolithic W+A
-            t0 = time.monotonic()
-            leaves = dec.fetch_sync(u)                   # blocking I/O
-            t_io = time.monotonic()
-            applied[u], mesh_tree = self._apply_unit(
-                u, constructed[u].abstract, leaves)
+            with trace.record("R", u):                   # unit idles (DMA)
+                leaves = dec.fetch_sync(u)               # blocking I/O
+            with trace.record("A", u):
+                applied[u], mesh_tree = self._apply_unit(
+                    u, constructed[u].abstract, leaves)
             if mesh_tree is not None:
                 sharded[u] = mesh_tree
-            t1 = time.monotonic()
-            trace.add_event("R", u, t0, t_io)            # unit idles (DMA)
-            trace.add_event("A", u, t_io, t1)
             trace.record_memory(u, constructed[u].mem_bytes,
-                                constructed[u].t_construct_end, t1)
+                                constructed[u].t_construct_end,
+                                time.monotonic())
         state: Dict[str, Any] = {"batch": batch}
         for u in units:                                  # all E
             with trace.record("E", u):
@@ -387,8 +386,9 @@ class ColdStartEngine:
                     state["logits" if u == units[-1] else "x"])
                 if u == units[-1] and on_logits is not None:
                     on_logits(state["logits"])
-        params = self._assemble_sharded(sharded) if self.mesh is not None \
-            else self.model.assemble(applied)
+        with jax.profiler.TraceAnnotation("coldstart.assemble"):
+            params = self._assemble_sharded(sharded) \
+                if self.mesh is not None else self.model.assemble(applied)
         return LoadResult(state["logits"], params, trace,
                           self.strategy.name)
 
@@ -408,6 +408,7 @@ class ColdStartEngine:
                               mesh=self.mesh, rules=self.rules)
         PipelineRuntime(standard_units(ctx), state).run()
 
-        params = self._assemble(state)
+        with jax.profiler.TraceAnnotation("coldstart.assemble"):
+            params = self._assemble(state)
         return LoadResult(state.get(OUTPUT, "logits"), params, trace,
                           strat.name)

@@ -43,7 +43,6 @@ Algorithm-1-critical — load is about to apply.
 from __future__ import annotations
 
 import threading
-import time
 from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable, Dict, Hashable, List, Optional, Tuple
 
@@ -210,16 +209,12 @@ class WeightDecoupler:
 
     # -------------------------------------------------- unit-granular path
     @staticmethod
-    def _src_meta(src: str, meta: Optional[Dict[str, Any]] = None
-                  ) -> Optional[Dict[str, Any]]:
+    def _src_meta(src: str) -> Dict[str, Any]:
         """Trace annotation of a stream's byte source: origin reads are
         unmarked, cache hits carry ``cached``, peer-exchange transfers
         carry ``peer``."""
-        if src == "cache":
-            meta = dict(meta or (), cached=True)
-        elif src == "peer":
-            meta = dict(meta or (), peer=True)
-        return meta
+        return {"cache": {"cached": True}, "peer": {"peer": True}}.get(
+            src, {})
 
     def _progress_cb(self, unit: str, total: int, shard: Hashable = 0):
         """Per-chunk progress callback for source-driven transfers
@@ -238,10 +233,9 @@ class WeightDecoupler:
             self.scheduler.on_issue(unit)
             with self.cv:           # waiters recompute Algorithm 1 deadlines
                 self.cv.notify_all()
-            t0 = time.monotonic()
-            leaves, src = self._retrieve(unit, st)
-            self.trace.add_event("R", unit, t0, time.monotonic(),
-                                 meta=self._src_meta(src))
+            with self.trace.record("R", unit) as meta:
+                leaves, src = self._retrieve(unit, st)
+                meta.update(self._src_meta(src))
             self.scheduler.on_complete(unit, observed=(src == "origin"))
             with self.cv:
                 self.ready[unit] = leaves
@@ -323,10 +317,9 @@ class WeightDecoupler:
             self.scheduler.on_issue(unit, shard=shard)
             with self.cv:
                 self.cv.notify_all()
-            t0 = time.monotonic()
-            payload, src = self._retrieve_shard(unit, shard, st, data)
-            meta = self._src_meta(src, {"shard": shard})
-            self.trace.add_event("R", unit, t0, time.monotonic(), meta=meta)
+            with self.trace.record("R", unit, {"shard": shard}) as meta:
+                payload, src = self._retrieve_shard(unit, shard, st, data)
+                meta.update(self._src_meta(src))
             self.scheduler.on_complete(unit, observed=(src == "origin"),
                                        shard=shard)
             with self.cv:                   # unit fully read: admit next

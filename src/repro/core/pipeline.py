@@ -16,6 +16,10 @@ execution, T = per-shard weight transform (dequant/cast fused into the
 shard committer's placement lane under a mesh — previously invisible to
 the trace because it happens inside R's landing path, before A).
 Thread-safe; timestamps are ``time.monotonic()``.
+:meth:`PipelineTrace.record` also opens a profiler host span per event
+(``coldstart.L`` ...), so a profiler trace of a load carries the stages
+on the device's clock; :meth:`PipelineTrace.add_event` is for events
+timed on another thread.
 
 T events carry ``meta={"shard": <device index>}`` and live on their own
 Gantt row; they are *excluded* from the default busy/utilization stage
@@ -25,9 +29,12 @@ overlap-eligible I/O time.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import time
 from typing import Dict, Iterable, List, Optional, Tuple
+
+import jax
 
 from repro import analysis
 
@@ -71,22 +78,21 @@ class PipelineTrace:
     def finish(self):
         self.t_end = time.monotonic()
 
-    def record(self, stage: str, layer: str):
-        """Context manager timing one stage event."""
-        trace = self
-
-        class _Ctx:
-            def __enter__(self):
-                self.ts = time.monotonic()
-                return self
-
-            def __exit__(self, *exc):
-                te = time.monotonic()
-                with trace._lock:
-                    trace.events.append(StageEvent(stage, layer, self.ts, te))
-                return False
-
-        return _Ctx()
+    @contextlib.contextmanager
+    def record(self, stage: str, layer: str, meta: Optional[dict] = None):
+        """Time one stage event of ``layer`` around a ``with`` block, on
+        the thread doing the work.  The same interval is a profiler host
+        span ``coldstart.<stage>`` (``unit=<layer>``), so a device trace
+        shows which stage ran in each idle gap.  Yields the event's meta
+        dict, which the block may fill; an empty one is stored as None."""
+        meta = dict(meta or ())
+        with jax.profiler.TraceAnnotation(f"coldstart.{stage}", unit=layer):
+            ts = time.monotonic()
+            try:
+                yield meta
+            finally:
+                self.add_event(stage, layer, ts, time.monotonic(),
+                               meta or None)
 
     def add_event(self, stage: str, layer: str, t_start: float, t_end: float,
                   meta: Optional[dict] = None):

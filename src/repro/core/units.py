@@ -260,14 +260,12 @@ class FusedWeightUnit(PipelineUnit):
         ctx = self.ctx
         for u in ctx.units:
             cu = ctx.state.wait_for(CONSTRUCTED, u)
-            t0 = time.monotonic()
-            leaves = ctx.decoupler.fetch_sync(u)
-            t_io = time.monotonic()
-            params, mesh_tree = ctx.apply_leaves(u, cu.abstract, leaves)
-            t1 = time.monotonic()
-            ctx.trace.add_event("R", u, t0, t_io)
-            ctx.trace.add_event("A", u, t_io, t1)
-            ctx.trace.record_memory(u, cu.mem_bytes, cu.t_construct_end, t1)
+            with ctx.trace.record("R", u):
+                leaves = ctx.decoupler.fetch_sync(u)
+            with ctx.trace.record("A", u):
+                params, mesh_tree = ctx.apply_leaves(u, cu.abstract, leaves)
+            ctx.trace.record_memory(u, cu.mem_bytes, cu.t_construct_end,
+                                    time.monotonic())
             if mesh_tree is not None:
                 ctx.state.publish(SHARDED, u, mesh_tree)
             ctx.state.publish(APPLIED, u, params)
@@ -284,7 +282,8 @@ class ComputeUnit(PipelineUnit):
         st: Dict[str, Any] = {"batch": ctx.batch}
         last = ctx.units[-1]
         for u in ctx.units:
-            params = ctx.state.wait_for(APPLIED, u)
+            with jax.profiler.TraceAnnotation("coldstart.E.wait", unit=u):
+                params = ctx.state.wait_for(APPLIED, u)
             with ctx.trace.record("E", u):
                 st = ctx.apply_fn(u)(params, st)
                 jax.block_until_ready(st["logits" if u == last else "x"])

@@ -27,6 +27,7 @@ independent of what the other slots hold.
 """
 from __future__ import annotations
 
+import contextlib
 import dataclasses
 import functools
 import time
@@ -43,6 +44,10 @@ from repro.serving import kvpages
 from repro.serving.api import CacheOverflowError, GenerateSpec
 
 PyTree = Any
+
+# the scheduler's profiler host spans (``decode.step`` ...), on the
+# device trace's clock
+_span = jax.profiler.TraceAnnotation
 
 
 # ---------------------------------------------------------------------------
@@ -427,13 +432,14 @@ class DecodeScheduler:
                                         first_token, t_first)
         n_new = validate_spec(spec, n_prompt, self.cache_len)
 
-        cache1 = self.model.init_cache(1, self.cache_len)
-        logits, cache1 = self._prefill(self.params, {"tokens": prompt},
-                                       cache1)
-        if first_token is None:
-            jax.block_until_ready(logits)
-            first_token = sample_first(logits, spec, n_prompt)
-            t_first = time.monotonic()
+        with _span("decode.first_token"), _span("decode.prefill"):
+            cache1 = self.model.init_cache(1, self.cache_len)
+            logits, cache1 = self._prefill(self.params, {"tokens": prompt},
+                                           cache1)
+            if first_token is None:
+                jax.block_until_ready(logits)
+                first_token = sample_first(logits, spec, n_prompt)
+                t_first = time.monotonic()
 
         req = _Active(spec, cache1, int(first_token), float(t_first),
                       n_prompt, n_new)
@@ -464,52 +470,59 @@ class DecodeScheduler:
             # its capacity ceiling applies unchanged
             n_new = validate_spec(spec, n_prompt, self.cache_len)
         need = -(-(n_prompt + n_new) // pt)
-        hit: List[int] = []
-        if self._prefix_ok:
-            hashes = kvpages.page_hashes(self.kvpool.model_key,
-                                         np.asarray(prompt)[0], pt)
-            # a hit must leave a non-empty prefill suffix (the request's
-            # own logits come from its last prompt token)
-            hashes_full = hashes
-            hashes = hashes[:min(len(hashes), (n_prompt - 1) // pt)]
-            hit = self.kvpool.match_prefix(hashes)
-        else:
-            hashes_full = []
-        try:
-            new = self.kvpool.alloc(need - len(hit), timeout=120.0)
-        except TimeoutError:
-            # our own prefix pins may be what is starving the pool: drop
-            # them and queue for the whole span like a cold request
-            self.kvpool.release(hit)
-            hit = []
-            new = self.kvpool.alloc(need)
-        page_ids = list(hit) + list(new)
-        n_hit = len(hit)
-        try:
-            cache1 = self.model.init_request_cache(need * pt, self.cache_len)
-            off = n_hit * pt
-            if off:
-                with self._cv:
-                    pools = self._kvpages   # hit pages are pinned ⇒ immutable
-                cache1 = self._gather(
-                    cache1, pools, jnp.asarray(np.asarray(hit, np.int32)))
-                logits, cache1 = self._prefill_cont(
-                    self.params, {"tokens": prompt[:, off:]}, cache1, off)
+        with _span("decode.first_token"):
+            hit: List[int] = []
+            if self._prefix_ok:
+                hashes = kvpages.page_hashes(self.kvpool.model_key,
+                                             np.asarray(prompt)[0], pt)
+                # a hit must leave a non-empty prefill suffix (the
+                # request's own logits come from its last prompt token)
+                hashes_full = hashes
+                hashes = hashes[:min(len(hashes), (n_prompt - 1) // pt)]
+                hit = self.kvpool.match_prefix(hashes)
             else:
-                logits, cache1 = self._prefill(self.params,
-                                               {"tokens": prompt}, cache1)
-            if first_token is None:
-                jax.block_until_ready(logits)
-                first_token = sample_first(logits, spec, n_prompt)
-                t_first = time.monotonic()
-            req = _Active(spec, cache1, int(first_token), float(t_first),
-                          n_prompt, n_new)
-            req.page_ids = page_ids
-            req.n_hit = n_hit
-            req.hashes = hashes_full
-        except BaseException:
-            self.kvpool.release(page_ids)
-            raise
+                hashes_full = []
+            with _span("decode.kv_alloc"):
+                try:
+                    new = self.kvpool.alloc(need - len(hit), timeout=120.0)
+                except TimeoutError:
+                    # our own prefix pins may be what is starving the
+                    # pool: drop them and queue for the whole span like
+                    # a cold request
+                    self.kvpool.release(hit)
+                    hit = []
+                    new = self.kvpool.alloc(need)
+            page_ids = list(hit) + list(new)
+            n_hit = len(hit)
+            try:
+                with _span("decode.prefill"):
+                    cache1 = self.model.init_request_cache(need * pt,
+                                                           self.cache_len)
+                    off = n_hit * pt
+                    if off:
+                        with self._cv:  # hit pages are pinned ⇒ immutable
+                            pools = self._kvpages
+                        cache1 = self._gather(
+                            cache1, pools,
+                            jnp.asarray(np.asarray(hit, np.int32)))
+                        logits, cache1 = self._prefill_cont(
+                            self.params, {"tokens": prompt[:, off:]},
+                            cache1, off)
+                    else:
+                        logits, cache1 = self._prefill(
+                            self.params, {"tokens": prompt}, cache1)
+                    if first_token is None:
+                        jax.block_until_ready(logits)
+                        first_token = sample_first(logits, spec, n_prompt)
+                        t_first = time.monotonic()
+                req = _Active(spec, cache1, int(first_token),
+                              float(t_first), n_prompt, n_new)
+                req.page_ids = page_ids
+                req.n_hit = n_hit
+                req.hashes = hashes_full
+            except BaseException:
+                self.kvpool.release(page_ids)
+                raise
         if req.remaining == 0 or (spec.eos_id is not None
                                   and req.tokens[-1] == spec.eos_id):
             self.kvpool.release(page_ids)
@@ -634,69 +647,75 @@ class DecodeScheduler:
         """Drive batched steps until ``my`` completes.  Exactly one
         thread steps at a time; the others wait on the CV.  Every
         resident request has a caller thread parked here, so a stepper
-        always exists while work remains."""
+        always exists while work remains.  A step's ``decode.step``
+        span runs from taking ``_stepping`` to releasing it."""
         while True:
-            with self._cv:
-                while True:
-                    if my.done or my.error is not None:
-                        return
-                    if not self._stepping:
-                        break
-                    self._cv.wait()
-                self._stepping = True
-                try:
-                    self._admit_locked()
-                    params, cache = self.params, self._cache
-                    tok = jnp.asarray(self._tok)
-                    pos = jnp.asarray(self._pos)
-                    seed = jnp.asarray(self._seed)
-                    temp = jnp.asarray(self._temp)
-                    if self.paged:
-                        pools = self._kvpages
-                        tables = jnp.asarray(self._tables)
-                except BaseException as e:
-                    # anything failing while _stepping is set must fail
-                    # ALL residents, or their threads wait forever
-                    self._fail_locked(e)
-                    raise
-            try:
-                if self.paged:
-                    nxt, new_cache, new_pools = self._pstep(
-                        params, cache, pools, tables, tok, pos, seed, temp)
-                else:
-                    nxt, new_cache = self._step(params, cache, tok, pos,
-                                                seed, temp)
-                nxt_host = np.asarray(nxt)
-            except BaseException as e:
+            with contextlib.ExitStack() as step:
                 with self._cv:
-                    self._fail_locked(e)
-                raise
-            t_now = time.monotonic()
-            with self._cv:
-                self._cache = new_cache
-                if self.paged:
-                    self._kvpages = new_pools
-                self.steps += 1
-                for slot in list(self._slots):
-                    req = self._slots[slot]
-                    t = int(nxt_host[slot, 0])
-                    req.tokens.append(t)
-                    req.times.append(t_now)
-                    req.remaining -= 1
-                    self._tok[slot, 0] = t
-                    self._pos[slot] += 1
-                    if req.remaining == 0 or \
-                            (req.spec.eos_id is not None
-                             and t == req.spec.eos_id):
-                        req.done = True
-                        del self._slots[slot]
-                        self._free.append(slot)
+                    while True:
+                        if my.done or my.error is not None:
+                            return
+                        if not self._stepping:
+                            break
+                        self._cv.wait()
+                    self._stepping = True
+                    step.enter_context(_span("decode.step"))
+                    try:
+                        with _span("decode.admit"):
+                            self._admit_locked()
+                        params, cache = self.params, self._cache
+                        tok = jnp.asarray(self._tok)
+                        pos = jnp.asarray(self._pos)
+                        seed = jnp.asarray(self._seed)
+                        temp = jnp.asarray(self._temp)
                         if self.paged:
-                            self._leave_paged_locked(req, slot)
-                self._m_steps.inc()
-                self._m_occ.set(len(self._slots))
-                self._stepping = False
-                self._cv.notify_all()
+                            pools = self._kvpages
+                            tables = jnp.asarray(self._tables)
+                    except BaseException as e:
+                        # anything failing while _stepping is set must
+                        # fail ALL residents, or their threads wait
+                        # forever
+                        self._fail_locked(e)
+                        raise
+                try:
+                    if self.paged:
+                        nxt, new_cache, new_pools = self._pstep(
+                            params, cache, pools, tables, tok, pos, seed,
+                            temp)
+                    else:
+                        nxt, new_cache = self._step(params, cache, tok,
+                                                    pos, seed, temp)
+                    nxt_host = np.asarray(nxt)
+                except BaseException as e:
+                    with self._cv:
+                        self._fail_locked(e)
+                    raise
+                t_now = time.monotonic()
+                with self._cv:
+                    self._cache = new_cache
+                    if self.paged:
+                        self._kvpages = new_pools
+                    self.steps += 1
+                    for slot in list(self._slots):
+                        req = self._slots[slot]
+                        t = int(nxt_host[slot, 0])
+                        req.tokens.append(t)
+                        req.times.append(t_now)
+                        req.remaining -= 1
+                        self._tok[slot, 0] = t
+                        self._pos[slot] += 1
+                        if req.remaining == 0 or \
+                                (req.spec.eos_id is not None
+                                 and t == req.spec.eos_id):
+                            req.done = True
+                            del self._slots[slot]
+                            self._free.append(slot)
+                            if self.paged:
+                                self._leave_paged_locked(req, slot)
+                    self._m_steps.inc()
+                    self._m_occ.set(len(self._slots))
+                    self._stepping = False
+                    self._cv.notify_all()
 
 
 # ---------------------------------------------------------------------------

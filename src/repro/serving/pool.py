@@ -165,12 +165,15 @@ class FunctionInstance:
         if self.scheduler is None:
             with self._sched_lock:
                 if self.scheduler is None:
-                    self.scheduler = DecodeScheduler(
-                        self.model, self.params, n_slots=self.gen_slots,
-                        cache_len=self.gen_cache_len,
-                        kv_page_tokens=self.kv_page_tokens,
-                        kv_budget_bytes=self.kv_budget_bytes,
-                        metrics=self.metrics)
+                    with jax.profiler.TraceAnnotation(
+                            "instance.scheduler_init"):
+                        self.scheduler = DecodeScheduler(
+                            self.model, self.params,
+                            n_slots=self.gen_slots,
+                            cache_len=self.gen_cache_len,
+                            kv_page_tokens=self.kv_page_tokens,
+                            kv_budget_bytes=self.kv_budget_bytes,
+                            metrics=self.metrics)
         return self.scheduler
 
     def generate(self, spec: GenerateSpec, *,

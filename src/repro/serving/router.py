@@ -25,6 +25,7 @@ import time
 from concurrent.futures import Future
 from typing import Any, Dict, Optional
 
+import jax
 import numpy as np
 
 from concurrent.futures import InvalidStateError
@@ -235,7 +236,11 @@ class Router:
                                                self._in_flight)
             self.metrics.gauge("router/in_flight").add(1)
             try:
-                result, info = service(inst, *rest)
+                # the request's own spans nest under this one, on this
+                # thread
+                with jax.profiler.TraceAnnotation("router.dispatch",
+                                                  req=req.req_id):
+                    result, info = service(inst, *rest)
             finally:
                 with self._cv:
                     self._in_flight -= 1
