@@ -10,17 +10,25 @@ Ring-buffer sliding-window caches are supported: slot ``j`` of a cache
 with ``S_max == window`` holds absolute position ``p`` where
 ``p ≡ j (mod window)``; validity is derived in-kernel from ``pos``.
 
-:func:`decode_attention_paged` is the block-paged variant: K/V live in
-a shared physical pool of fixed-size pages (``(n_pages, K, pt, dh)``)
-and each batch row owns a *page table* mapping logical page j to a
-physical page id.  The split-K grid already tiles the cache sequence,
-so paging is purely an index-map change — the table rides the scalar
-prefetch channel (``num_scalar_prefetch=2``) and logical cache block
-``s`` is fetched from physical block ``(table[b, s // r], s % r)``
-where ``r = pt // bs``.  The kernel body (online softmax, GQA packing,
-ring-window validity over *logical* positions) is shared verbatim with
-the slotted kernel; unallocated table entries may point anywhere —
-their positions are beyond ``pos``, so masking zeroes them exactly.
+:func:`decode_attention_paged` is the block-paged variant, a kernel of
+its own: K/V live in a shared physical pool of fixed-size pages
+(``(n_pages, K, pt, dh)``) and each batch row owns a *page table*
+mapping logical page j to a physical page id.  Its work follows each
+row's ``pos``, not the table width: a work list built before the call
+gives one grid step per chunk of a row's live pages (``pos // pt + 1``,
+or every page once a ring has wrapped), rows in order, so the grid
+itself is as long as the live pages.  Each step fetches whole pages,
+all KV heads at once, through one BlockSpec per page of the chunk (the
+table and the work list ride scalar prefetch), and the pipeline fetches
+step i+1's pages while step i computes.  A slot of the chunk past the
+live count points at page 0, so a run of short rows fetches it once.
+The body scores every query head against every key of the chunk with
+two matmuls, masks keys of other KV heads and positions past ``pos``
+(or outside the ring window), and keeps the online softmax in float32
+per query head.  Table entries past the live pages are never used.
+Pages come through BlockSpecs rather than manual DMAs from a pool in
+``pl.ANY``: Mosaic refuses to slice an HBM ref whose minor dimension
+(a head size of 64) is narrower than its 128-lane tile.
 """
 from __future__ import annotations
 
@@ -157,6 +165,76 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     return out.reshape(B, H, dh)
 
 
+def _live_pages(pos, pt: int, n_tbl: int, window: int):
+    """Logical pages of a row that hold a key position ``pos`` attends
+    to: ``[0, pos]`` for a full cache; every page once a ring wraps."""
+    n = jnp.minimum(pos // pt + 1, n_tbl)
+    if window > 0:
+        n = jnp.where(pos >= window, n_tbl, n)
+    return n
+
+
+def _paged_kernel(pos_ref, tbl_ref, work_ref, q_ref, *refs, window: int,
+                  n_tbl: int, chunk: int, rep: int, scale: float):
+    k_refs, v_refs = refs[:chunk], refs[chunk:2 * chunk]
+    o_ref, m_scr, l_scr, acc_scr = refs[2 * chunk:]
+    _, K, pt, dh = k_refs[0].shape
+    H = K * rep
+    w = work_ref[pl.program_id(0)]
+    b, c0 = w // n_tbl, w % n_tbl    # row, logical index of its first page
+    pos = pos_ref[b]
+
+    @pl.when(c0 == 0)
+    def _init():
+        m_scr[...] = jnp.full_like(m_scr, NEG_INF)
+        l_scr[...] = jnp.zeros_like(l_scr)
+        acc_scr[...] = jnp.zeros_like(acc_scr)
+
+    # every query head against every key of the chunk's pages, keys of
+    # other KV heads masked out: two matmuls a step, whatever K is
+    def rows(refs_):
+        blocks = [r[0].astype(jnp.float32).reshape(K * pt, dh)
+                  for r in refs_]
+        return blocks[0] if chunk == 1 else jnp.concatenate(blocks, axis=0)
+
+    q = q_ref[0].astype(jnp.float32)                          # (H, dh)
+    k, v = rows(k_refs), rows(v_refs)                         # (n, dh)
+    n = chunk * K * pt
+    s = jax.lax.dot_general(q, k, (((1,), (1,)), ((), ())),
+                            preferred_element_type=jnp.float32)
+    s = s * scale                                             # (H, n)
+    head = jax.lax.broadcasted_iota(jnp.int32, (H, n), 0) // rep
+    col = jax.lax.broadcasted_iota(jnp.int32, (H, n), 1)
+    in_page = col % (K * pt)
+    # a page past the live count holds another page's keys: its logical
+    # positions lie past pos, so the mask drops it like any dead slot
+    idx = (c0 + col // (K * pt)) * pt + in_page % pt
+    if window > 0:
+        p_at = pos - ((pos - idx) % window)
+        valid = jnp.logical_and(p_at >= 0, p_at > pos - window)
+    else:
+        valid = idx <= pos
+    valid = jnp.logical_and(valid, in_page // pt == head)
+    s = jnp.where(valid, s, NEG_INF)
+
+    m_prev = m_scr[:, :1]
+    m_new = jnp.maximum(m_prev, jnp.max(s, axis=-1, keepdims=True))
+    alpha = jnp.exp(m_prev - m_new)
+    p = jnp.exp(s - m_new)
+    l_new = alpha * l_scr[:, :1] + jnp.sum(p, axis=-1, keepdims=True)
+    pv = jax.lax.dot_general(p, v, (((1,), (0,)), ((), ())),
+                             preferred_element_type=jnp.float32)
+    acc_scr[...] = acc_scr[...] * alpha + pv
+    m_scr[...] = jnp.broadcast_to(m_new, m_scr.shape)
+    l_scr[...] = jnp.broadcast_to(l_new, l_scr.shape)
+
+    @pl.when(c0 + chunk >= _live_pages(pos, pt, n_tbl, window))
+    def _finalize():
+        l = l_scr[:, :1]
+        l = jnp.where(l == 0.0, 1.0, l)
+        o_ref[0] = (acc_scr[...] / l).astype(o_ref.dtype)
+
+
 @functools.partial(
     jax.jit, static_argnames=("window", "bs", "interpret"))
 def decode_attention_paged(q: jax.Array, k_pages: jax.Array,
@@ -168,56 +246,58 @@ def decode_attention_paged(q: jax.Array, k_pages: jax.Array,
     across the batch; tables: (B, NP) int32 physical page ids (logical
     sequence extent NP * pt per row); pos: (B,).  Returns (B, H, dh).
 
-    The tile divides ``pt`` (:func:`seq_tile`) so every grid block
-    lives inside one page.  Ring-window semantics are identical to the
-    slotted kernel over the logical extent.
+    The grid has one step per chunk of a row's live pages, rows in
+    order; a chunk is ``bs // (K * pt)`` pages (at least one), so a step
+    holds about ``bs`` keys over all KV heads.  Ring-window semantics
+    are the slotted kernel's over the logical extent.
     """
     B, H, dh = q.shape
     K, pt = k_pages.shape[1], k_pages.shape[2]
     NP = tables.shape[1]
-    rep = H // K
-    bs = seq_tile(bs, pt)
-    r = pt // bs                     # cache blocks per page
-    ns = NP * r
+    chunk = max(1, bs // (K * pt))
+    pos = pos.astype(jnp.int32)
+    # the work list: step s covers row b's pages [c0, c0 + chunk), for
+    # work[s] = b * NP + c0; row b takes its steps after row b - 1's
+    n_steps = -(-_live_pages(pos, pt, NP, window) // chunk)
+    ends = jnp.cumsum(n_steps)
+    steps = jnp.arange(B * -(-NP // chunk), dtype=jnp.int32)
+    done = ends[None, :] <= steps[:, None]               # row b is behind s
+    row = jnp.minimum(jnp.sum(done, axis=1), B - 1)
+    first = jnp.sum(jnp.where(done, n_steps[None, :], 0), axis=1)
+    work = (row * NP + jnp.minimum((steps - first) * chunk, NP - 1)
+            ).astype(jnp.int32)
 
-    qr = q.reshape(B, K, rep, dh)
-    grid = (B, K, ns)
-    # the body is the slotted kernel's: s_lo = si * bs is the *logical*
-    # offset of block si, which the shared masking math consumes; only
-    # the fetch location below goes through the page table
-    kern = functools.partial(_kernel, window=window, bs=bs, ns=ns, rep=rep,
+    def page_spec(i):
+        def index(s, pos_ref, tbl_ref, work_ref):
+            b, j = work_ref[s] // NP, work_ref[s] % NP + i
+            # a page past the live count maps to page 0, which the
+            # pipeline then fetches once for a run of short rows
+            live = j < _live_pages(pos_ref[b], pt, NP, window)
+            return (jnp.where(live, tbl_ref[b * NP + jnp.minimum(j, NP - 1)],
+                              0), 0, 0, 0)
+        return pl.BlockSpec((1, K, pt, dh), index)
+
+    row_spec = pl.BlockSpec((1, H, dh), lambda s, p, t, w: (w[s] // NP, 0, 0))
+    pages = [page_spec(i) for i in range(chunk)]
+    kern = functools.partial(_paged_kernel, window=window, n_tbl=NP,
+                             chunk=chunk, rep=H // K,
                              scale=1.0 / math.sqrt(dh))
-
-    def paged_kern(pos_ref, tbl_ref, *rest):
-        del tbl_ref                  # consumed by the index maps only
-        kern(pos_ref, *rest)
-
-    def kv_map(b, h, s, pos_ref, tbl_ref):
-        del pos_ref
-        return (tbl_ref[b, s // r], h, s % r, 0)
-
-    out = pl.pallas_call(
-        paged_kern,
+    return pl.pallas_call(
+        kern,
         grid_spec=pltpu.PrefetchScalarGridSpec(
-            num_scalar_prefetch=2,
-            grid=grid,
-            in_specs=[
-                pl.BlockSpec((1, 1, rep, dh),
-                             lambda b, h, s, *_: (b, h, 0, 0)),
-                pl.BlockSpec((1, 1, bs, dh), kv_map),
-                pl.BlockSpec((1, 1, bs, dh), kv_map),
-            ],
-            out_specs=pl.BlockSpec((1, 1, rep, dh),
-                                   lambda b, h, s, *_: (b, h, 0, 0)),
+            num_scalar_prefetch=3,
+            grid=(ends[-1],),
+            in_specs=[row_spec] + pages + pages,
+            out_specs=row_spec,
             scratch_shapes=[
-                pltpu.VMEM((rep, LANES), jnp.float32),
-                pltpu.VMEM((rep, LANES), jnp.float32),
-                pltpu.VMEM((rep, dh), jnp.float32),
+                pltpu.VMEM((H, LANES), jnp.float32),
+                pltpu.VMEM((H, LANES), jnp.float32),
+                pltpu.VMEM((H, dh), jnp.float32),
             ],
         ),
-        out_shape=jax.ShapeDtypeStruct((B, K, rep, dh), q.dtype),
+        out_shape=jax.ShapeDtypeStruct((B, H, dh), q.dtype),
         compiler_params=pltpu.CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("arbitrary",)),
         interpret=interpret,
-    )(pos.astype(jnp.int32), tables.astype(jnp.int32), qr, k_pages, v_pages)
-    return out.reshape(B, H, dh)
+    )(pos, tables.astype(jnp.int32).reshape(-1), work, q,
+      *[k_pages] * chunk, *[v_pages] * chunk)

@@ -376,8 +376,8 @@ def decode_attention_paged(q: jax.Array, k_pages: jax.Array,
     (P, K, pt, dh) shared across the batch; tables (B, NP) int32 page
     ids per row; pos (B,). -> (B, H, dh).
 
-    The kernel tile divides the page size (every cache block lives
-    inside one physical page).
+    The kernel reads each row's live pages only (``pos // pt + 1``), a
+    chunk of whole pages per grid step.
     """
     mode = registry.dispatch("decode_attention_paged")
     if mode == "ref":
@@ -390,11 +390,11 @@ def decode_attention_paged(q: jax.Array, k_pages: jax.Array,
 
 def _probe_decode_paged():
     _decode_paged_pallas.lower(
-        jnp.zeros((1, 2, 128), jnp.float32),
-        jnp.zeros((2, 1, 128, 128), jnp.float32),
-        jnp.zeros((2, 1, 128, 128), jnp.float32),
-        jnp.zeros((1, 2), jnp.int32),
-        jnp.zeros((1,), jnp.int32), window=0, bs=128).compile()
+        jnp.zeros((2, 2, 128), jnp.bfloat16),
+        jnp.zeros((5, 1, 16, 128), jnp.bfloat16),
+        jnp.zeros((5, 1, 16, 128), jnp.bfloat16),
+        jnp.zeros((2, 4), jnp.int32),
+        jnp.zeros((2,), jnp.int32), window=0, bs=32).compile()
 
 
 _register("decode_attention_paged", _decode_paged_pallas,

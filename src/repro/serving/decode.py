@@ -409,6 +409,8 @@ class DecodeScheduler:
         # shared across all schedulers of a platform: occupancy/steps
         # aggregate over instances (the decode capacity the node runs)
         self._m_steps = m.counter("decode/steps")
+        # pages the paged kernel fetches per layer: its work follows pos
+        self._m_pages = m.counter("decode/kv_pages_attended")
         self._m_joined = m.counter("decode/joined")
         self._m_occ = m.gauge("decode/occupancy")
 
@@ -622,8 +624,10 @@ class DecodeScheduler:
 
     def _leave_paged_locked(self, req: _Active, slot: int):
         """Release a leaver's page references and park its table row on
-        the scratch page (caller holds the lock)."""
+        the scratch page at position 0, so the dead row costs the
+        kernel one page (caller holds the lock)."""
         self._tables[slot, :] = self.kvpool.scratch_id
+        self._pos[slot] = 0
         self.kvpool.release(req.page_ids)
         req.page_ids = []
 
@@ -638,6 +642,7 @@ class DecodeScheduler:
                 req.page_ids = []
         if self.paged:
             self._tables[:, :] = self.kvpool.scratch_id
+            self._pos[:] = 0
         self._slots.clear()
         self._pending.clear()
         self._free = list(range(self.n_slots))
@@ -671,6 +676,8 @@ class DecodeScheduler:
                         if self.paged:
                             pools = self._kvpages
                             tables = jnp.asarray(self._tables)
+                            pages = int((self._pos // self.page_tokens
+                                         + 1).sum())
                     except BaseException as e:
                         # anything failing while _stepping is set must
                         # fail ALL residents, or their threads wait
@@ -713,6 +720,8 @@ class DecodeScheduler:
                             if self.paged:
                                 self._leave_paged_locked(req, slot)
                     self._m_steps.inc()
+                    if self.paged:
+                        self._m_pages.inc(pages)
                     self._m_occ.set(len(self._slots))
                     self._stepping = False
                     self._cv.notify_all()

@@ -203,6 +203,58 @@ def test_decode_paged_ops_dispatch():
                                atol=2e-5, rtol=2e-5)
 
 
+def _live_page_case(B, H, K, dh, pt, NP, pos, dtype, scratch_rows=()):
+    """Pools of random pages and tables of distinct ids up to each row's
+    live extent, random (garbage) ids beyond it; ``scratch_rows`` are
+    dead rows whose whole table is the scratch page (the last)."""
+    r = np.random.default_rng(11)
+    P = B * NP + 1
+    k_pages = arr(P, K, pt, dh, dtype=dtype)
+    v_pages = arr(P, K, pt, dh, dtype=dtype)
+    tables = r.integers(0, P, (B, NP)).astype(np.int32)
+    perm = r.permutation(P - 1)
+    for b in range(B):
+        tables[b, :] = (P - 1 if b in scratch_rows
+                        else perm[b * NP:(b + 1) * NP])
+        n_live = min(pos[b] // pt + 1, NP)
+        if b not in scratch_rows and n_live < NP:
+            tables[b, n_live:] = r.integers(0, P, NP - n_live)
+    return (arr(B, H, dh, dtype=dtype), k_pages, v_pages,
+            jnp.asarray(tables), jnp.asarray(pos, jnp.int32))
+
+
+@pytest.mark.parametrize("B,H,K,dh,pt,NP,pos,window,bs,dtype,dead", [
+    # the chat cell's heads, bf16 pools, rows of 1 to 96 live pages
+    (4, 15, 5, 64, 16, 160, [0, 300, 1535, 2047], 0, 512, jnp.bfloat16,
+     ()),
+    (3, 4, 4, 32, 16, 8, [5, 64, 127], 0, 128, jnp.float32, ()),   # rep 1
+    (3, 8, 2, 32, 16, 8, [5, 64, 127], 0, 128, jnp.float32, ()),   # rep 4
+    # a dead row at position 0 on a table of scratch pages
+    (3, 8, 2, 32, 16, 8, [0, 40, 0], 0, 128, jnp.float32, (0, 2)),
+    # the last position of a page and the first of the next
+    (4, 8, 2, 32, 16, 8, [15, 16, 47, 48], 0, 64, jnp.float32, ()),
+    # 6 and 7 live pages against a chunk of 4 (bs // (K * pt))
+    (2, 8, 2, 32, 16, 8, [90, 100], 0, 128, jnp.float32, ()),
+    # a table twice the live extent, the rest garbage ids
+    (2, 8, 2, 32, 16, 16, [20, 70], 0, 64, jnp.float32, ()),
+    # a 128-position ring before and after it wraps
+    (4, 8, 2, 32, 16, 8, [3, 127, 128, 300], 128, 64, jnp.float32, ()),
+], ids=["chat-heads", "rep1", "rep4", "dead-row", "page-edges",
+        "partial-chunk", "wide-table", "ring-window"])
+def test_decode_paged_live_pages(B, H, K, dh, pt, NP, pos, window, bs,
+                                 dtype, dead):
+    """The paged kernel reads each row's live pages only: it matches the
+    gather-everything oracle whatever the table holds past ``pos``."""
+    q, k_pages, v_pages, tables, pos = _live_page_case(
+        B, H, K, dh, pt, NP, pos, dtype, scratch_rows=dead)
+    o_ref = ref.decode_attention_paged(q, k_pages, v_pages, tables, pos,
+                                       window=window)
+    o = paged_pallas(q, k_pages, v_pages, tables, pos, window=window,
+                     bs=bs, interpret=True)
+    np.testing.assert_allclose(np.asarray(o, np.float32),
+                               np.asarray(o_ref, np.float32), **tol(dtype))
+
+
 # ---------------------------------------------------------------------------
 # SSD
 # ---------------------------------------------------------------------------

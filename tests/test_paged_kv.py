@@ -379,3 +379,38 @@ def test_kv_metrics_wired(dense):
     st = sched.stats()
     assert st["kv_pages_total"] == sched.n_pages
     assert st["kv_page_tokens"] == PT
+
+
+def test_paged_leave_resets_pos_and_counts_attended_pages(dense):
+    """A leaver's slot goes back to position 0 on the scratch page (a
+    dead row costs the paged kernel one page), and every batched step
+    adds the pages the kernel fetches, sum(pos // pt + 1) over all rows,
+    to ``decode/kv_pages_attended``."""
+    cfg, m, params = dense
+    reg = MetricsRegistry()
+    sched = DecodeScheduler(m, params, n_slots=2, cache_len=CACHE_LEN,
+                            kv_page_tokens=PT, kv_max_seq=CACHE_LEN,
+                            metrics=reg)
+    attended = reg.counter("decode/kv_pages_attended")
+    seen = []                               # (pos, counter before step)
+    step = sched._pstep
+
+    def spy(params, cache, pools, tables, tok, pos, *rest):
+        seen.append((np.asarray(pos).copy(), attended.value))
+        return step(params, cache, pools, tables, tok, pos, *rest)
+
+    sched._pstep = spy
+    sched.generate(GenerateSpec(prompt=_prompt(cfg, 1, n=2 * PT + 3),
+                                n_new=4))
+    assert sched._pos.tolist() == [0, 0]
+    assert (sched._tables == sched.kvpool.scratch_id).all()
+    sched.generate(GenerateSpec(prompt=_prompt(cfg, 2, n=PT - 1), n_new=3))
+    assert sched._pos.tolist() == [0, 0]
+    assert len(seen) == 3 + 2 == reg.counter("decode/steps").value
+    after = [c for _, c in seen[1:]] + [attended.value]
+    for (pos, before), a in zip(seen, after):
+        assert a - before == int((pos // PT + 1).sum()), (pos, before, a)
+    # each request decodes from its prompt's end in slot 0; the idle
+    # slot sits at position 0 throughout
+    assert seen[0][0].tolist() == [2 * PT + 3, 0]
+    assert seen[3][0].tolist() == [PT - 1, 0]

@@ -91,11 +91,15 @@ def test_decode_attention_compiles(one_chip, B, S_max):
              window=0, bs=KB.decode_bs)
 
 
-def test_decode_attention_paged_compiles(one_chip):
-    B, pages, pt = 4, 129, 16
+@pytest.mark.parametrize("B,pages,NP", [
+    (4, 129, 32),
+    (64, 2289, 160),   # 64 slots of 2560 positions, a 1.5 GB page budget
+])
+def test_decode_attention_paged_compiles(one_chip, B, pages, NP):
+    pt = 16
     _compile(decode_attention_paged, one_chip,
              [((B, H, DH), BF), ((pages, K, pt, DH), BF),
-              ((pages, K, pt, DH), BF), ((B, 32), I32), ((B,), I32)],
+              ((pages, K, pt, DH), BF), ((B, NP), I32), ((B,), I32)],
              window=0, bs=KB.decode_bs)
 
 
