@@ -31,6 +31,7 @@ seed's unit-granular path exactly.
 """
 from __future__ import annotations
 
+import collections
 import dataclasses
 import time
 from typing import Any, Dict, Optional
@@ -46,7 +47,7 @@ from repro.core.pipeline import PipelineTrace
 from repro.core.scheduler import PriorityAwareScheduler
 from repro.core.shards import ShardedUnitData, UnitShardPlan, plan_unit
 from repro.core.strategies import Strategy, get_strategy
-from repro.core.units import (APPLIED, OUTPUT, SHARDED, PipelineContext,
+from repro.core.units import (OUTPUT, SHARDED, PipelineContext,
                               PipelineRuntime, PipelineState, standard_units)
 from repro.distributed.sharding import (ShardingRules, leaf_specs,
                                         param_specs, serve_rules)
@@ -60,11 +61,74 @@ PyTree = Any
 
 @dataclasses.dataclass
 class LoadResult:
-    logits: jax.Array            # first-request output (computed in-pipeline)
-    params: PyTree               # assembled steady-state parameters (on the
-                                 # mesh, sharded, when the engine has one)
+    logits: Optional[jax.Array]  # first-request output (computed in-pipeline)
+    params: Optional[PyTree]     # assembled steady-state parameters (on the
+                                 # mesh, sharded, when the engine has one);
+                                 # both None once the instance is evicted
     trace: PipelineTrace
     strategy: str
+
+
+def _write(stacked: PyTree, unit: PyTree, i) -> PyTree:
+    return jax.tree.map(
+        lambda s, x: jax.lax.dynamic_update_index_in_dim(s, x, i, 0),
+        stacked, unit)
+
+
+# the stacked buffers are donated, so each write updates them in place
+_write_unit = jax.jit(_write, donate_argnums=0)
+_read_unit = jax.jit(lambda stacked, i: jax.tree.map(lambda s: s[i],
+                                                     stacked))
+
+
+class Placement:
+    """The steady-state params of one single-device load, filled in as
+    the load goes.
+
+    A block unit is written into its index of the scan-stacked params by
+    a donated in-place update, keyed by index; each slot's stacked
+    buffers are allocated once, at its first unit.  Whole units (embed,
+    final, tail blocks) are kept as they are.  So a load holds its
+    layers once, plus the applied units that E has not yet run, never a
+    second stacked copy.  The pipeline places a unit once E has run it:
+    the unit and the previous write are on the device by then, so the
+    write, dispatched and not awaited, starts at once (placed as soon as
+    A had applied it, the write held A up until the unit's host
+    transfer landed).
+    """
+
+    def __init__(self, model):
+        self.model = model
+        self.whole: Dict[str, PyTree] = {}
+        self.stacked: Dict[str, PyTree] = {}
+        self._count = collections.Counter(
+            at[0] for at in map(model.stack_index, model.unit_names())
+            if at is not None)
+
+    def place(self, unit: str, tree: PyTree):
+        at = self.model.stack_index(unit)
+        if at is None:
+            self.whole[unit] = tree
+            return
+        slot, i = at
+        with jax.profiler.TraceAnnotation("coldstart.place", unit=unit):
+            if slot not in self.stacked:
+                n = self._count[slot]
+                self.stacked[slot] = jax.tree.map(
+                    lambda x: jnp.zeros((n,) + x.shape, x.dtype), tree)
+            self.stacked[slot] = _write_unit(self.stacked[slot], tree, i)
+
+    def view(self, unit: str) -> PyTree:
+        """Unit ``unit``'s params as placed: for a block unit, its slice
+        of the stacked params (a copy of that one unit)."""
+        at = self.model.stack_index(unit)
+        if at is None:
+            return self.whole[unit]
+        slot, i = at
+        return _read_unit(self.stacked[slot], i)
+
+    def params(self) -> PyTree:
+        return self.model.assemble(self.whole, self.stacked)
 
 
 class ColdStartEngine:
@@ -143,10 +207,13 @@ class ColdStartEngine:
         names = self.model.unit_names()
         keys = jax.random.split(jax.random.key(0), len(names))
         state: Dict[str, Any] = {"batch": batch}
+        placement = self._placement()       # compiles the in-place write
         for name, k in zip(names, keys):
             self.model.abstract_unit(name)   # precompute static structure
             p = self.model.init_unit(name, k)
             state = self._apply_fn(name)(p, state)
+            if placement is not None:
+                placement.place(name, p)
         jax.block_until_ready(state["logits"])
 
     def _plan(self, unit: str) -> UnitShardPlan:
@@ -270,13 +337,11 @@ class ColdStartEngine:
         mesh_tree = unflatten_unit(abstract, dev)
         return compute, mesh_tree
 
-    def _assemble(self, state: PipelineState) -> PyTree:
-        """Stack the applied units into the steady-state params — on
-        the mesh (sharded, from the committed per-device buffers) when
-        the engine has one, on the default device otherwise."""
-        if self.mesh is None:
-            return self.model.assemble(state.peek(APPLIED))
-        return self._assemble_sharded(state.peek(SHARDED))
+    def _placement(self) -> Optional[Placement]:
+        """Where a single-device load builds its steady-state params;
+        None under a mesh, whose params are assembled on the mesh from
+        the committed shards."""
+        return Placement(self.model) if self.mesh is None else None
 
     def _assemble_sharded(self, units_dev: Dict[str, PyTree]) -> PyTree:
         if self._assemble_jit is None:
@@ -365,30 +430,37 @@ class ColdStartEngine:
                 constructed[u] = miniloader.construct_unit(
                     self.model, u, k, mini=False,
                     mesh=self.mesh, rules=self.rules)
-        applied = {}
+        placement = self._placement()
+        applied = {}                 # under a mesh: E's default-device trees
         sharded = {}
         for u in units:                                  # monolithic W+A
             with trace.record("R", u):                   # unit idles (DMA)
                 leaves = dec.fetch_sync(u)               # blocking I/O
             with trace.record("A", u):
-                applied[u], mesh_tree = self._apply_unit(
+                tree, mesh_tree = self._apply_unit(
                     u, constructed[u].abstract, leaves)
-            if mesh_tree is not None:
-                sharded[u] = mesh_tree
+            if placement is not None:
+                placement.place(u, tree)
+            else:
+                applied[u], sharded[u] = tree, mesh_tree
+            del tree
             trace.record_memory(u, constructed[u].mem_bytes,
                                 constructed[u].t_construct_end,
                                 time.monotonic())
         state: Dict[str, Any] = {"batch": batch}
         for u in units:                                  # all E
             with trace.record("E", u):
-                state = self._apply_fn(u)(applied[u], state)
+                uparams = placement.view(u) if placement is not None \
+                    else applied.pop(u)
+                state = self._apply_fn(u)(uparams, state)
+                del uparams
                 jax.block_until_ready(
                     state["logits" if u == units[-1] else "x"])
                 if u == units[-1] and on_logits is not None:
                     on_logits(state["logits"])
         with jax.profiler.TraceAnnotation("coldstart.assemble"):
             params = self._assemble_sharded(sharded) \
-                if self.mesh is not None else self.model.assemble(applied)
+                if placement is None else placement.params()
         return LoadResult(state["logits"], params, trace,
                           self.strategy.name)
 
@@ -400,15 +472,19 @@ class ColdStartEngine:
         if strat.decouple:
             dec.prefetch(units)                 # issue I/O at request arrival
 
+        placement = self._placement()
         ctx = PipelineContext(model=self.model, units=list(units),
                               keys=list(keys), batch=batch, strategy=strat,
                               trace=trace, decoupler=dec, scheduler=scheduler,
                               state=state, apply_leaves=self._apply_unit,
                               apply_fn=self._apply_fn, on_output=on_logits,
+                              place=None if placement is None
+                              else placement.place,
                               mesh=self.mesh, rules=self.rules)
         PipelineRuntime(standard_units(ctx), state).run()
 
         with jax.profiler.TraceAnnotation("coldstart.assemble"):
-            params = self._assemble(state)
+            params = self._assemble_sharded(state.peek(SHARDED)) \
+                if placement is None else placement.params()
         return LoadResult(state.get(OUTPUT, "logits"), params, trace,
                           strat.name)

@@ -74,6 +74,12 @@ class PipelineState:
             self._slots.setdefault(stage, {})[unit] = value
             self.cv.notify_all()
 
+    def drop(self, stage: str, unit: str):
+        """Let go of ``unit``'s value at ``stage``, which stays marked
+        done: the consumer has used it, and its buffers may be freed."""
+        with self.cv:
+            self._slots[stage][unit] = True
+
     def fail(self, exc: BaseException):
         with self.cv:
             if not any(e is exc for e in self.errors):
@@ -138,6 +144,9 @@ class PipelineContext:
     # leaves when a mesh is attached (None otherwise)
     apply_leaves: Callable[[str, PyTree, Any], Any]
     apply_fn: Callable[[str], Callable]
+    # (unit, compute_tree) -> None: writes a unit E has run into the
+    # steady-state params (single-device loads; None under a mesh)
+    place: Optional[Callable[[str, PyTree], None]] = None
     # sharded cold start: resolved mesh + rules (None -> seed path)
     mesh: Any = None
     rules: Any = None
@@ -224,6 +233,7 @@ class DecoupledWeightUnit(PipelineUnit):
             if mesh_tree is not None:
                 ctx.state.publish(SHARDED, u, mesh_tree)
             ctx.state.publish(APPLIED, u, params)
+            del params, mesh_tree       # E holds the unit until it has run
             pending.discard(u)
 
     def _next_ready(self, pending) -> str:
@@ -269,6 +279,7 @@ class FusedWeightUnit(PipelineUnit):
             if mesh_tree is not None:
                 ctx.state.publish(SHARDED, u, mesh_tree)
             ctx.state.publish(APPLIED, u, params)
+            del params, mesh_tree
 
 
 class ComputeUnit(PipelineUnit):
@@ -290,6 +301,13 @@ class ComputeUnit(PipelineUnit):
                 if u == last and ctx.on_output is not None:
                     # first token sampled inside the final E event
                     ctx.on_output(st["logits"])
+            if ctx.place is not None:
+                # E's result is ready, so the device has the unit and
+                # the previous write: this donated write starts at once
+                ctx.place(u, params)
+            # E has used the unit: the steady-state params hold it now
+            del params
+            ctx.state.drop(APPLIED, u)
         ctx.state.publish(OUTPUT, "logits", st["logits"])
 
 

@@ -10,8 +10,9 @@ depth — this keeps HLO size ~constant for the 40-cell dry-run matrix.
 The *streaming* view (``unit_names`` / ``init_unit`` / ``abstract_unit``
 / ``unit_apply`` / ``assemble``) exposes per-layer granularity for the
 cold-start pipeline: the paper's L_i / W_i+A_i / E_i execution units map
-to one unit here, and ``assemble`` stacks the applied units back into
-the steady-state representation once the model is fully live.
+to one unit here; ``stack_index`` says where each unit lands in the
+steady-state representation, which the cold start fills in place, and
+``assemble`` gathers it once the model is fully live.
 """
 from __future__ import annotations
 
@@ -450,7 +451,10 @@ class LM:
     def prefill(self, params: PyTree, batch: Dict[str, jax.Array],
                 cache: PyTree, *, unroll: bool = False
                 ) -> Tuple[jax.Array, PyTree]:
-        """Run the full prompt, fill the cache.  Returns (logits, cache)."""
+        """Run the full prompt, fill the cache.  Returns (logits, cache):
+        the logits of the last position alone, (B, 1, V), the only ones
+        a request samples from; a head over every position would cost
+        S x V more work and output."""
         cfg = self.cfg
         x = self.embed(params, batch)
         S = x.shape[1]
@@ -474,7 +478,7 @@ class LM:
             x, c2 = block_prefill(cfg, kind, params["blocks"][f"t{t}"], x,
                                   positions, cache[f"t{t}"])
             out_cache[f"t{t}"] = c2
-        return self._head(params, x), out_cache
+        return self._head(params, x[:, -1:]), out_cache
 
     def _scan_units(self, body, carry, xs, unroll: bool):
         """scan over the stacked pattern units, or a Python loop when
@@ -538,7 +542,7 @@ class LM:
                                     cache[f"t{t}"], off, chunk)
                 cache[f"t{t}"] = c2
             if ci == S // chunk - 1:
-                logits = self._head(params, x)
+                logits = self._head(params, x[:, -1:])
         return logits, cache
 
     def prefill_continue(self, params: PyTree, batch: Dict[str, jax.Array],
@@ -547,8 +551,8 @@ class LM:
         """Resume a full-attention prefill at absolute position ``off``:
         cache rows ``[0, off)`` already hold valid K/V (gathered from
         shared prefix pages) and ``batch["tokens"]`` is the *suffix*.
-        Returns (logits for the suffix, cache filled through
-        ``off + suffix_len``).  This is the prefix-cache fast path —
+        Returns (the last position's logits, (B, 1, V), and the cache
+        filled through ``off + suffix_len``).  This is the prefix-cache fast path —
         TTFT work is proportional to the unshared suffix only.
         """
         cfg = self.cfg
@@ -576,7 +580,7 @@ class LM:
             x, c2 = block_chunk(cfg, kind, params["blocks"][f"t{t}"], x,
                                 cache[f"t{t}"], off, cs)
             out_cache[f"t{t}"] = c2
-        return self._head(params, x), out_cache
+        return self._head(params, x[:, -1:]), out_cache
 
     def decode_step(self, params: PyTree, cache: PyTree, tokens: jax.Array,
                     pos: jax.Array, *, unroll: bool = False
@@ -855,10 +859,30 @@ class LM:
                 lambda: self.init_unit(name, jax.random.key(0)))
         return self._abstract_units[name]
 
-    def assemble(self, units: Dict[str, PyTree]) -> PyTree:
+    def stack_index(self, name: str) -> Optional[Tuple[str, int]]:
+        """Where unit ``name`` sits in the scan-stacked params:
+        ``(slot key, index)``, or None for a unit kept whole (embed,
+        final, tail blocks)."""
+        if not name.startswith("block_"):
+            return None
+        j = int(name.split("_")[1])
+        u = len(self.pattern)
+        if j >= self.n_units * u:
+            return None
+        return f"s{j % u}", j // u
+
+    def assemble(self, units: Dict[str, PyTree],
+                 stacked: Optional[Dict[str, PyTree]] = None) -> PyTree:
+        """The steady-state params from the units.  ``stacked``: slot
+        key -> that slot's blocks already stacked (the cold start writes
+        each unit into them in place), so only the whole units are read
+        from ``units``; without it the block units are stacked here."""
         u = len(self.pattern)
         blocks: Dict[str, PyTree] = {}
         for slot in range(u):
+            if stacked is not None:
+                blocks[f"s{slot}"] = stacked[f"s{slot}"]
+                continue
             per = [units[f"block_{i * u + slot:03d}"]
                    for i in range(self.n_units)]
             blocks[f"s{slot}"] = jax.tree.map(lambda *xs: jnp.stack(xs), *per)

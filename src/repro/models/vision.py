@@ -295,7 +295,11 @@ class VisionModel:
                 lambda: self.init_unit(name, jax.random.key(0)))
         return self._abstract_units[name]
 
-    def assemble(self, units: Dict[str, PyTree]) -> PyTree:
+    def stack_index(self, name: str) -> None:
+        """Every unit is kept whole: nothing is scan-stacked."""
+        return None
+
+    def assemble(self, units: Dict[str, PyTree], stacked=None) -> PyTree:
         return dict(units)
 
     def init(self, key: jax.Array) -> PyTree:

@@ -73,8 +73,9 @@ def sample_tokens(logits: jax.Array, seed: jax.Array, next_pos: jax.Array,
 
 
 def sample_first(logits, spec: GenerateSpec, n_prompt: int) -> int:
-    """First token from full-prompt logits ((1, S, V): prefill output or
-    the cold pipeline's in-flight forward)."""
+    """First token from the prompt's logits: the prefill's (1, 1, V) of
+    the last position, or the cold pipeline's (1, S, V) in-flight
+    forward."""
     return int(sample_tokens(
         logits[:, -1, :],
         jnp.asarray([spec.seed], jnp.uint32),
@@ -226,9 +227,10 @@ def _prefill_fn(model, fingerprint):
     would silently reuse executables that baked the previous kernels
     in.  A fresh closure per cache entry gives each (model, modes) pair
     its own trace while still sharing it across schedulers of the same
-    model (scale-out)."""
+    model (scale-out).  The empty cache is donated: the filled one
+    takes its place, so a queued prefill holds one."""
     return jax.jit(lambda params, batch, cache:
-                   model.prefill(params, batch, cache))
+                   model.prefill(params, batch, cache), donate_argnums=2)
 
 
 @functools.lru_cache(maxsize=16)
@@ -294,10 +296,13 @@ def _gather_fn(model, fingerprint):
 
 @functools.lru_cache(maxsize=16)
 def _pack_fn(model, fingerprint):
+    """The pool is donated: the joins of one step boundary pack in turn
+    into one pool, where each would otherwise allocate a new one while
+    its input waits in the device queue."""
     return jax.jit(
         lambda pools, cache, ids, first:
         model.pack_pages(pools, cache, ids, first),
-        static_argnums=(3,))
+        static_argnums=(3,), donate_argnums=0)
 
 
 class DecodeScheduler:
@@ -502,11 +507,13 @@ class DecodeScheduler:
                                                            self.cache_len)
                     off = n_hit * pt
                     if off:
-                        with self._cv:  # hit pages are pinned ⇒ immutable
-                            pools = self._kvpages
-                        cache1 = self._gather(
-                            cache1, pools,
-                            jnp.asarray(np.asarray(hit, np.int32)))
+                        # hit pages are pinned, so immutable; dispatched
+                        # under the lock, before any later pack donates
+                        # the pool array it reads
+                        with self._cv:
+                            cache1 = self._gather(
+                                cache1, self._kvpages,
+                                jnp.asarray(np.asarray(hit, np.int32)))
                         logits, cache1 = self._prefill_cont(
                             self.params, {"tokens": prompt[:, off:]},
                             cache1, off)

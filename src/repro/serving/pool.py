@@ -31,6 +31,7 @@ model function and hands them out under two disciplines:
 """
 from __future__ import annotations
 
+import dataclasses
 import time
 from typing import Any, Callable, Dict, List, Optional, Tuple
 
@@ -143,6 +144,11 @@ class FunctionInstance:
     def evict(self):
         self.params = None
         self.scheduler = None          # slotted KV cache dies with the params
+        if self.last_load is not None:
+            # the load's trace stays readable; its params and first
+            # logits would pin the evicted weights on the device
+            self.last_load = dataclasses.replace(self.last_load,
+                                                 params=None, logits=None)
 
     def invoke(self, batch: Dict[str, jax.Array]) -> Tuple[jax.Array, dict]:
         """Returns (logits, {"cold": bool, "load_s": float, "infer_s"})."""

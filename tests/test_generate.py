@@ -73,7 +73,14 @@ def test_router_generation_bit_identical(arch, tmp_path):
                                    gen=GenerateSpec(prompt=prompts[0],
                                                     n_new=n_new))).result()
         assert r0.cold and r0.load_s > 0
-        assert r0.ttft_s < r0.load_s          # TTFT inside the load
+        # TTFT inside the load: the first token is sampled within the
+        # final unit's E event, before the pipeline ends
+        load = pool._instances[0].last_load.trace
+        e_last = next(e for e in load.events
+                      if e.stage == "E" and e.layer == m.unit_names()[-1])
+        t_first = r0.t_arrival + r0.ttft_s
+        assert load.t0 < e_last.t_start < t_first < e_last.t_end \
+            <= load.t_end
         # warm, concurrency 1
         r1 = router.submit(Request(req_id=1, model=arch,
                                    gen=GenerateSpec(prompt=prompts[1],

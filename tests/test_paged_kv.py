@@ -414,3 +414,50 @@ def test_paged_leave_resets_pos_and_counts_attended_pages(dense):
     # slot sits at position 0 throughout
     assert seen[0][0].tolist() == [2 * PT + 3, 0]
     assert seen[3][0].tolist() == [PT - 1, 0]
+
+
+def test_pack_and_prefill_donate_their_buffers(dense):
+    """Admission holds one page pool and one cache per request on the
+    device: a pack consumes the pool it writes into, and a prefill the
+    empty cache it fills, so the joins of one step boundary never
+    allocate a pool each while they wait in the device queue.  A
+    prefix hit, which reads the pool, is dispatched before a later
+    pack consumes it, and still generates its reference tokens."""
+    cfg, m, params = dense
+    sched = DecodeScheduler(m, params, n_slots=2, cache_len=CACHE_LEN,
+                            kv_page_tokens=PT, kv_max_seq=CACHE_LEN)
+    seen = {"pack": [], "prefill": []}
+    pack, prefill = sched._pack, sched._prefill
+
+    def spy_pack(pools, cache, ids, first):
+        seen["pack"].append(jax.tree.leaves(pools))
+        return pack(pools, cache, ids, first)
+
+    def spy_prefill(p, batch, cache):
+        seen["prefill"].append(jax.tree.leaves(cache))
+        return prefill(p, batch, cache)
+
+    sched._pack, sched._prefill = spy_pack, spy_prefill
+    shared = _prompt(cfg, 200, n=2 * PT)
+    prompts = [np.concatenate([shared, _prompt(cfg, 201 + i, n=5)])
+               for i in range(3)]
+    out = [None] * len(prompts)
+
+    def go(i):
+        out[i] = sched.generate(GenerateSpec(prompt=prompts[i], n_new=5,
+                                             seed=i))
+
+    go(0)
+    threads = [threading.Thread(target=go, args=(i,)) for i in (1, 2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert len(seen["pack"]) == 3 and len(seen["prefill"]) == 1
+    for leaves in seen["pack"] + seen["prefill"]:
+        assert all(x.is_deleted() for x in leaves)
+    assert sched.kvpool.stats().prefix_hits == 4
+    for i, p in enumerate(prompts):
+        assert out[i].tokens == reference_generate(
+            m, params, p, n_new=5, cache_len=CACHE_LEN, seed=i)
+    sched.kvpool.check_invariants()

@@ -10,6 +10,7 @@ import glob
 import os
 import sys
 import threading
+import types
 from collections import Counter
 
 import jax
@@ -26,7 +27,7 @@ from repro.store.store import WeightStore, deploy_model
 ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
 if ROOT not in sys.path:
     sys.path.insert(0, ROOT)
-from bench.lib import trace as trace_mod  # noqa: E402
+from bench.lib import harness, spec, trace as trace_mod  # noqa: E402
 
 PT = 16
 PROMPTS = (19, 8)
@@ -112,6 +113,44 @@ def test_one_stage_span_per_unit(traced):
         got = [s["unit"] for rows in traced["lines"]
                for nm, _, _, s in rows if nm == name]
         assert sorted(got) == sorted(units), name
+
+
+def test_one_place_span_per_block_unit(traced):
+    """Each block unit's write into the stacked params is one
+    ``coldstart.place`` span naming it, inside the load; the whole
+    units (embed, final) are kept as applied, with no write."""
+    blocks = [u for u in traced["units"] if u.startswith("block_")]
+    got = [s["unit"] for rows in traced["lines"]
+           for nm, _, _, s in rows if nm == "coldstart.place"]
+    assert sorted(got) == blocks
+    ev = traced["events"]
+    load = next(e for e in ev if e.name == "coldstart.load")
+    assemble = next(e for e in ev if e.name == "coldstart.assemble")
+    for e in ev:
+        if e.name == "coldstart.place":
+            assert load.start_ns <= e.start_ns and \
+                e.end_ns <= assemble.start_ns
+
+
+def test_place_reader_on_the_traced_load(traced):
+    """``coldstart.place_ms`` reads the load's place spans and its
+    assemble span; a trace with no place span, as a program that builds
+    its params by stacking gives, reads None."""
+    ev = traced["events"]
+
+    def read(events):
+        t0 = min(e.start_ns for e in events) * 1e-9
+        t1 = max(e.end_ns for e in events) * 1e-9
+        run = harness.Run(cell=types.SimpleNamespace(config={}), seed=0,
+                          seconds=1.0, w0=t0, w1=t1, records=[],
+                          counters={}, trace=trace_mod.View(events),
+                          trace_window=(t0, t1))
+        return spec.load_module("metrics", "coldstart.place_ms").read(run)
+
+    want = sum(e.dur_ns for e in ev
+               if e.name in ("coldstart.place", "coldstart.assemble"))
+    assert read(ev) == pytest.approx(want * 1e-6)
+    assert read([e for e in ev if e.name != "coldstart.place"]) is None
 
 
 def test_in_memory_events_match_the_profiler(traced):

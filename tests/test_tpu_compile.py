@@ -133,3 +133,26 @@ def test_kernel_on_a_four_chip_mesh_compiles(four_chips, monkeypatch, op):
         shapes = [((4, H, DH), BF), ((4, K, 512, DH), BF),
                   ((4, K, 512, DH), BF), ((4,), I32)]
     _compile(fn, four_chips, shapes)
+
+
+YI_H, YI_K, YI_DH = 32, 4, 128     # yi-9b: 8 query heads per KV head
+
+
+@pytest.mark.parametrize("kernel", ["flash_attention",
+                                    "decode_attention_paged"])
+def test_yi_widths_compile(one_chip, kernel):
+    """yi-9b-l8.batch's shapes: a 2048-token prompt's flash attention,
+    and the paged decode of 64 slots over a 1.2 GB pool of 16-token
+    pages (head size 128, lane-aligned, unlike smollm's 64)."""
+    if kernel == "flash_attention":
+        _compile(flash_attention, one_chip,
+                 [((1, YI_H, 2048, YI_DH), BF), ((1, YI_K, 2048, YI_DH), BF),
+                  ((1, YI_K, 2048, YI_DH), BF)],
+                 causal=True, window=0, bq=KB.flash_bq, bk=KB.flash_bk)
+    else:
+        pages, pt = 4578, 16
+        _compile(decode_attention_paged, one_chip,
+                 [((64, YI_H, YI_DH), BF), ((pages, YI_K, pt, YI_DH), BF),
+                  ((pages, YI_K, pt, YI_DH), BF), ((64, 160), I32),
+                  ((64,), I32)],
+                 window=0, bs=KB.decode_bs)
