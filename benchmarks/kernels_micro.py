@@ -116,14 +116,15 @@ def run(args=None):
     P = 2 * npg + 2                       # + 2 unreferenced pages
     perm = r.permutation(P)[:2 * npg]
     tbl = jnp.asarray(perm.reshape(2, npg).astype(np.int32))
-    kp = jnp.asarray(r.standard_normal((P, K, pt, dh)), jnp.bfloat16)
+    # one layer's pool, K and V of a page side by side in lanes
+    kvp = jnp.asarray(r.standard_normal((1, P, K, pt, 2 * dh)), jnp.bfloat16)
     paged_modes = [m for m in modes if m != "pallas"
                    or ops.registry.pallas_supported("decode_attention_paged")]
     _sweep(rows, f"decode_attention_paged_512_pt{pt}",
-           lambda: (jax.jit(lambda q, a, b, t, p:
-                            ops.decode_attention_paged(q, a, b, t, p)),
-                    (q1, kp, kp, tbl, pos)), paged_modes,
-           ref_bytes=2 * 2 * npg * pt * K * dh * kp.dtype.itemsize)
+           lambda: (jax.jit(lambda q, a, t, p:
+                            ops.decode_attention_paged(q, a, t, p, 0)),
+                    (q1, kvp, tbl, pos)), paged_modes,
+           ref_bytes=2 * 2 * npg * pt * K * dh * kvp.dtype.itemsize)
 
     x = jnp.asarray(r.standard_normal((1, 4, 256, 64)), jnp.float32)
     dt = jnp.abs(jnp.asarray(r.standard_normal((1, 4, 256)),

@@ -88,7 +88,7 @@ def logit_tol(n_layers: int) -> float:
     return 0.025 * n_layers ** 0.5
 
 MAIN_PATH_KERNELS = ("flash_attention", "decode_attention",
-                     "decode_attention_paged")
+                     "decode_attention_paged", "kv_pages_write")
 
 
 # ---------------------------------------------------------------------------

@@ -55,6 +55,7 @@ from repro.kernels.flash_attention import flash_attention as _flash_pallas
 from repro.kernels.decode_attention import decode_attention as _decode_pallas
 from repro.kernels.decode_attention import (
     decode_attention_paged as _decode_paged_pallas)
+from repro.kernels.decode_attention import kv_pages_write as _kv_write_pallas
 from repro.kernels.ssd_scan import ssd_scan as _ssd_pallas
 from repro.kernels.rglru_scan import rglru_scan as _rglru_pallas
 from repro.kernels.weight_transform import weight_transform as _wt_pallas
@@ -369,36 +370,63 @@ def _probe_decode():
 _register("decode_attention", _decode_pallas, _probe_decode)
 
 
-def decode_attention_paged(q: jax.Array, k_pages: jax.Array,
-                           v_pages: jax.Array, tables: jax.Array,
-                           pos: jax.Array, *, window: int = 0) -> jax.Array:
-    """Block-paged decode attention: q (B, H, dh); page pools
-    (P, K, pt, dh) shared across the batch; tables (B, NP) int32 page
-    ids per row; pos (B,). -> (B, H, dh).
+def decode_attention_paged(q: jax.Array, kv_pages: jax.Array,
+                           tables: jax.Array, pos: jax.Array,
+                           layer: jax.Array, *, window: int = 0
+                           ) -> jax.Array:
+    """Block-paged decode attention: q (B, H, dh); kv_pages (L, P, K, pt,
+    2 dh), the stacked page pools with K and V side by side in lanes;
+    tables (B, NP) int32 page ids per row; pos (B,); layer, the pool
+    read. -> (B, H, dh).
 
     The kernel reads each row's live pages only (``pos // pt + 1``), a
-    chunk of whole pages per grid step.
+    chunk of whole pages per grid step, out of the stacked pool.
     """
     mode = registry.dispatch("decode_attention_paged")
     if mode == "ref":
-        return ref.decode_attention_paged(q, k_pages, v_pages, tables, pos,
+        return ref.decode_attention_paged(q, kv_pages, tables, pos, layer,
                                           window=window)
-    return _on_devices(_decode_paged_pallas, q, k_pages, v_pages, tables,
-                       pos, window=window, bs=_blocks().decode_bs,
+    return _on_devices(_decode_paged_pallas, q, kv_pages, tables, pos,
+                       layer, window=window, bs=_blocks().decode_bs,
                        interpret=mode == "interpret")
 
 
 def _probe_decode_paged():
     _decode_paged_pallas.lower(
-        jnp.zeros((2, 2, 128), jnp.bfloat16),
-        jnp.zeros((5, 1, 16, 128), jnp.bfloat16),
-        jnp.zeros((5, 1, 16, 128), jnp.bfloat16),
+        jnp.zeros((2, 2, 64), jnp.bfloat16),
+        jnp.zeros((1, 5, 1, 16, 128), jnp.bfloat16),
         jnp.zeros((2, 4), jnp.int32),
-        jnp.zeros((2,), jnp.int32), window=0, bs=32).compile()
+        jnp.zeros((2,), jnp.int32), jnp.int32(0), window=0,
+        bs=32).compile()
 
 
 _register("decode_attention_paged", _decode_paged_pallas,
           _probe_decode_paged)
+
+
+def kv_pages_write(kv_pages: jax.Array, layer: jax.Array, pg: jax.Array,
+                   row: jax.Array, kv: jax.Array) -> jax.Array:
+    """``kv[b]`` (K, R, 2 dh) into page ``pg[b]`` of pool ``layer[b]`` (or
+    one ``layer``) of the stacked ``kv_pages`` (L, P, K, pt, 2 dh): a
+    decode step's row ``row[b]`` (R = 1) or a pack's whole pages (R =
+    pt).  The kernel aliases the pool onto its output, so a donated
+    pool is written in place, N pages a call."""
+    mode = registry.dispatch("kv_pages_write")
+    if mode == "ref":
+        return ref.kv_pages_write(kv_pages, layer, pg, row, kv)
+    return _on_devices(_kv_write_pallas, kv_pages, layer, pg, row, kv,
+                       interpret=mode == "interpret")
+
+
+def _probe_kv_write():
+    for rows in (1, 16):
+        _kv_write_pallas.lower(
+            jnp.zeros((1, 5, 1, 16, 128), jnp.bfloat16), jnp.int32(0),
+            jnp.zeros((2,), jnp.int32), jnp.zeros((2,), jnp.int32),
+            jnp.zeros((2, 1, rows, 128), jnp.bfloat16)).compile()
+
+
+_register("kv_pages_write", _kv_write_pallas, _probe_kv_write)
 
 
 # ---------------------------------------------------------------------------

@@ -95,26 +95,41 @@ def decode_attention(q: jax.Array, k_cache: jax.Array, v_cache: jax.Array,
     return out.reshape(B, H, dh).astype(q.dtype)
 
 
-def decode_attention_paged(q: jax.Array, k_pages: jax.Array,
-                           v_pages: jax.Array, tables: jax.Array,
-                           pos: jax.Array, *, window: int = 0) -> jax.Array:
+def decode_attention_paged(q: jax.Array, kv_pages: jax.Array,
+                           tables: jax.Array, pos: jax.Array,
+                           layer: jax.Array, *, window: int = 0
+                           ) -> jax.Array:
     """Block-paged decode attention: gather-then-attend oracle.
 
-    q: (B, H, dh); k_pages/v_pages: (P, K, pt, dh) — physical page pools
+    q: (B, H, dh); kv_pages: (L, P, K, pt, 2 dh) — the stacked physical
+    page pools of L layers, K in lanes [0, dh) and V in [dh, 2 dh),
     shared across the batch; tables: (B, NP) int32 page ids per row
-    (logical extent NP * pt); pos: (B,).
+    (logical extent NP * pt); pos: (B,); layer: which of the L pools.
 
     The definition: per batch row, gather its NP pages into the
     contiguous logical cache and run :func:`decode_attention` — so the
     math (masking of garbage rows beyond ``pos``, ring-window validity
     over logical positions, GQA) is *identical* to the slotted oracle.
     """
-    B = q.shape[0]
-    K, pt, dh = k_pages.shape[1], k_pages.shape[2], k_pages.shape[3]
+    B, dh = q.shape[0], q.shape[2]
+    K, pt = kv_pages.shape[2], kv_pages.shape[3]
     NP = tables.shape[1]
-    kc = jnp.swapaxes(k_pages[tables], 1, 2).reshape(B, K, NP * pt, dh)
-    vc = jnp.swapaxes(v_pages[tables], 1, 2).reshape(B, K, NP * pt, dh)
-    return decode_attention(q, kc, vc, pos, window=window)
+    kv = jnp.swapaxes(kv_pages[layer, tables], 1, 2).reshape(
+        B, K, NP * pt, 2 * dh)                          # (B, K, NP*pt, 2dh)
+    return decode_attention(q, kv[..., :dh], kv[..., dh:], pos,
+                            window=window)
+
+
+def kv_pages_write(kv_pages: jax.Array, layer: jax.Array, pg: jax.Array,
+                   row: jax.Array, kv: jax.Array) -> jax.Array:
+    """``kv[b]`` (K, R, 2 dh) into page ``pg[b]`` of pool ``layer[b]``
+    (or one ``layer``) of ``kv_pages`` (L, P, K, pt, 2 dh): at row
+    ``row[b]`` where R is 1, as the whole page where R is pt.  Writes
+    that share a page keep an arbitrary writer."""
+    kv = kv.astype(kv_pages.dtype)
+    if kv.shape[2] == kv_pages.shape[3]:
+        return kv_pages.at[layer, pg].set(kv)
+    return kv_pages.at[layer, pg, :, row].set(kv[:, :, 0])
 
 
 # ---------------------------------------------------------------------------

@@ -177,37 +177,31 @@ def attention_decode(cfg, p: PyTree, x: jax.Array, pos: jax.Array,
 
 
 def attention_decode_paged(cfg, p: PyTree, x: jax.Array, pos: jax.Array,
-                           k_pages: jax.Array, v_pages: jax.Array,
+                           kv_pages: jax.Array, layer: jax.Array,
                            tables: jax.Array):
     """Single-token decode through a block-paged KV pool.
 
-    x: (B, 1, D); k_pages/v_pages: (P, K, pt, dh) physical pools shared
-    across the batch; tables: (B, NP) int32 page ids per row; pos: (B,)
-    current position.  Row b's token is written at physical page
-    ``tables[b, pos // pt]``, row ``pos % pt``.  Inactive batch rows
-    carry tables full of the scratch page id, so their writes land in
-    scratch and their (discarded) outputs attend only scratch garbage.
-    Returns (y, k_pages, v_pages).
+    x: (B, 1, D); kv_pages: (L, P, K, pt, 2 dh) the stacked physical
+    pools shared across the batch, K and V of a page side by side in
+    lanes; layer: this layer's index into L; tables: (B, NP) int32 page
+    ids per row; pos: (B,) current position.  Row b's token is written
+    at physical page ``tables[b, pos // pt]``, row ``pos % pt``, in
+    place: the write reads and rewrites B pages, not the pool.  Inactive
+    batch rows carry tables full of the scratch page id, so their writes
+    land in scratch and their (discarded) outputs attend only scratch
+    garbage.  Distinct live rows never share a write page (each owns
+    its own).  Returns (y, kv_pages).
     """
     from repro.kernels import ops
     q, k, v = qkv_project(cfg, p, x, pos[:, None])
-    P, pt = k_pages.shape[0], k_pages.shape[2]
+    pt = kv_pages.shape[3]
     pg = jnp.take_along_axis(tables, (pos // pt)[:, None], axis=1)[:, 0]
-    row = pos % pt
-    # B-row scatter onto the addressed (page, row) cells — unlike the
-    # slotted arena (attention_decode's one-hot einsum, §Perf iteration
-    # 2c), a mask-select here would rewrite the *whole* pool every step
-    # and its cost would scale with the page budget, not the batch.
-    # Distinct live rows never collide (each owns its write page);
-    # inactive rows all land in the scratch page, where a duplicate-
-    # index scatter keeps an arbitrary writer — garbage either way,
-    # never read (the kernel masks positions > pos exactly and live
-    # tables never reference another row's pages)
-    k_pages = k_pages.at[pg, :, row].set(k[:, 0].astype(k_pages.dtype))
-    v_pages = v_pages.at[pg, :, row].set(v[:, 0].astype(v_pages.dtype))
-    o = ops.decode_attention_paged(q[:, 0], k_pages, v_pages, tables, pos)
+    kv = jnp.concatenate([k, v], axis=-1)               # (B, 1, K, 2dh)
+    kv_pages = ops.kv_pages_write(kv_pages, layer, pg, pos % pt,
+                                  jnp.swapaxes(kv, 1, 2))
+    o = ops.decode_attention_paged(q[:, 0], kv_pages, tables, pos, layer)
     y = attn_out(cfg, p, o[:, None])
-    return y, k_pages, v_pages
+    return y, kv_pages
 
 
 # ---------------------------------------------------------------------------

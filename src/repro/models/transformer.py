@@ -160,20 +160,21 @@ def _kind_paged(cfg, kind: str) -> bool:
 
 def block_decode_paged(cfg, kind: str, p: PyTree, x: jax.Array,
                        pos: jax.Array, cache: PyTree, pages: PyTree,
-                       tables: jax.Array):
+                       layer: jax.Array, tables: jax.Array):
     """Single-token decode with full-attention KV served from a page pool.
 
-    ``pages`` is ``{"k", "v"}`` of ``(P, K, pt, dh)`` for paged kinds and
-    ``None`` for kinds whose state stays slot-resident (then ``cache``
-    is the real per-slot state and this defers to :func:`block_decode`).
+    ``pages`` is the stacked ``(L, P, K, pt, 2 dh)`` pool of a paged
+    kind, read and written at index ``layer``, and ``None`` for kinds
+    whose state stays slot-resident (then ``cache`` is the real
+    per-slot state and this defers to :func:`block_decode`).
     Returns (x, cache, pages).
     """
     if pages is None:
         x, cache = block_decode(cfg, kind, p, x, pos, cache)
         return x, cache, None
     h = layers.apply_norm(cfg, p["norm1"], x)
-    y, kp, vp = layers.attention_decode_paged(cfg, p["attn"], h, pos,
-                                              pages["k"], pages["v"], tables)
+    y, pages = layers.attention_decode_paged(cfg, p["attn"], h, pos, pages,
+                                             layer, tables)
     x = x + y
     h = layers.apply_norm(cfg, p["norm2"], x)
     if kind == "moe":
@@ -181,7 +182,7 @@ def block_decode_paged(cfg, kind: str, p: PyTree, x: jax.Array,
         x = x + y
     else:
         x = x + layers.mlp_block(cfg, p["mlp"], h)
-    return x, cache, {"k": kp, "v": vp}
+    return x, cache, pages
 
 
 def block_chunk(cfg, kind: str, p: PyTree, x: jax.Array, cache_l: PyTree,
@@ -649,21 +650,23 @@ class LM:
         return n * per
 
     def init_kv_pages(self, n_pages: int, page_tokens: int) -> PyTree:
-        """Physical page pools: per paged group, ``{"k","v"}`` arrays of
-        ``(n_units, n_pages, K, pt, dh)`` (stacked) / ``(n_pages, K, pt,
-        dh)`` (tail).  Non-paged groups map to ``None``.  The caller
-        sizes ``n_pages`` to budget + 1 (the trailing scratch page)."""
+        """Physical page pools: per paged group, one array of ``(n_units,
+        n_pages, K, pt, 2 dh)`` (stacked) / ``(n_pages, K, pt, 2 dh)``
+        (tail), a page's keys in lanes ``[0, dh)`` and its values in
+        ``[dh, 2 dh)``: lane-dense (at dh 64 one 128-lane tile per head
+        and page), so the device's default layout is the kernels'.
+        Non-paged groups map to ``None``.  The caller sizes ``n_pages``
+        to budget + 1 (the trailing scratch page)."""
         cfg = self.cfg
         pools: Dict[str, PyTree] = {}
         for key, kind, stacked in self._cache_groups():
             if not _kind_paged(cfg, kind):
                 pools[key] = None
                 continue
-            shape = (n_pages, cfg.n_kv_heads, page_tokens, cfg.dh)
+            shape = (n_pages, cfg.n_kv_heads, page_tokens, 2 * cfg.dh)
             if stacked:
                 shape = (self.n_units,) + shape
-            pools[key] = {"k": jnp.zeros(shape, cfg.compute_dtype),
-                          "v": jnp.zeros(shape, cfg.compute_dtype)}
+            pools[key] = jnp.zeros(shape, cfg.compute_dtype)
         return pools
 
     def init_cache_paged(self, batch: int, cache_len: int) -> PyTree:
@@ -713,6 +716,11 @@ class LM:
                 out[key] = {"k": None, "v": None}
         return out
 
+    def _paged_groups(self):
+        """(key, stacked) of every paged group."""
+        return [(key, stacked) for key, kind, stacked in self._cache_groups()
+                if _kind_paged(self.cfg, kind)]
+
     def gather_pages(self, cache: PyTree, pools: PyTree,
                      ids: jax.Array) -> PyTree:
         """Copy physical pages ``ids`` (in logical order) into rows
@@ -720,24 +728,18 @@ class LM:
         the device half of a prefix-cache hit."""
         m = ids.shape[0]
         out = dict(cache)
-        for key, kind, stacked in self._cache_groups():
-            if not _kind_paged(self.cfg, kind):
-                continue
+        for key, stacked in self._paged_groups():
+            pool = pools[key] if stacked else pools[key][None]
+            u, _, K, pt, w = pool.shape
+            seg = jnp.swapaxes(pool[:, ids], 1, 2)         # (u,K,m,pt,2dh)
+            seg = seg.reshape(u, 1, K, m * pt, w)
             dst = {}
-            for n in ("k", "v"):
-                pool, c = pools[key][n], cache[key][n]
-                pt = pool.shape[-2]
-                if stacked:
-                    seg = jnp.swapaxes(pool[:, ids], 1, 2)     # (u,K,m,pt,dh)
-                    seg = seg.reshape(pool.shape[0], 1, pool.shape[2],
-                                      m * pt, pool.shape[4])
-                    dst[n] = jax.lax.dynamic_update_slice(
-                        c, seg.astype(c.dtype), (0, 0, 0, 0, 0))
-                else:
-                    seg = jnp.swapaxes(pool[ids], 0, 1)        # (K,m,pt,dh)
-                    seg = seg.reshape(1, pool.shape[1], m * pt, pool.shape[3])
-                    dst[n] = jax.lax.dynamic_update_slice(
-                        c, seg.astype(c.dtype), (0, 0, 0, 0))
+            for n, half in (("k", seg[..., :w // 2]), ("v", seg[..., w // 2:])):
+                c = cache[key][n]
+                if not stacked:
+                    half = half[0]
+                dst[n] = jax.lax.dynamic_update_slice(
+                    c, half.astype(c.dtype), (0,) * c.ndim)
             out[key] = dst
         return out
 
@@ -746,45 +748,35 @@ class LM:
         """Copy contiguous cache rows ``[first_page * pt, (first_page +
         len(ids)) * pt)`` out into physical pages ``ids`` — the device
         half of admission (prompt K/V moves from the per-request prefill
-        cache into the shared pool)."""
+        cache into the shared pool).  Whole pages, every layer's in one
+        write: on a donated pool, in place."""
+        from repro.kernels import ops
         m = int(ids.shape[0])
         out = dict(pools)
-        for key, kind, stacked in self._cache_groups():
-            if not _kind_paged(self.cfg, kind):
-                continue
-            dst = {}
-            for n in ("k", "v"):
-                pool, c = pools[key][n], cache[key][n]
-                pt = pool.shape[-2]
-                lo = first_page * pt
-                if stacked:
-                    seg = jax.lax.slice_in_dim(c[:, 0], lo, lo + m * pt,
-                                               axis=2)          # (u,K,m*pt,dh)
-                    seg = seg.reshape(c.shape[0], c.shape[2], m, pt,
-                                      c.shape[4])
-                    seg = jnp.swapaxes(seg, 1, 2)               # (u,m,K,pt,dh)
-                    dst[n] = pool.at[:, ids].set(seg.astype(pool.dtype))
-                else:
-                    seg = jax.lax.slice_in_dim(c[0], lo, lo + m * pt, axis=1)
-                    seg = seg.reshape(c.shape[1], m, pt, c.shape[3])
-                    seg = jnp.swapaxes(seg, 0, 1)               # (m,K,pt,dh)
-                    dst[n] = pool.at[ids].set(seg.astype(pool.dtype))
-            out[key] = dst
+        for key, stacked in self._paged_groups():
+            pool = pools[key] if stacked else pools[key][None]
+            pt = pool.shape[-2]
+            lo = first_page * pt
+            k, v = ((c if stacked else c[None])[:, 0, :, lo:lo + m * pt]
+                    for c in (cache[key]["k"], cache[key]["v"]))
+            seg = jnp.concatenate([k, v], axis=-1)         # (u,K,m*pt,2dh)
+            u, K, _, w = seg.shape
+            seg = jnp.swapaxes(seg.reshape(u, K, m, pt, w), 1, 2)
+            layer = jnp.repeat(jnp.arange(u, dtype=jnp.int32), m)
+            pool = ops.kv_pages_write(pool, layer, jnp.tile(ids, u),
+                                      jnp.zeros_like(layer),
+                                      seg.reshape(u * m, K, pt, w))
+            out[key] = pool if stacked else pool[0]
         return out
 
     def copy_page(self, pools: PyTree, src: int, dst: int) -> PyTree:
         """Device half of a copy-on-write fork: duplicate physical page
         ``src`` into ``dst`` across every paged layer."""
         out = dict(pools)
-        for key, kind, stacked in self._cache_groups():
-            if not _kind_paged(self.cfg, kind):
-                continue
-            if stacked:
-                out[key] = {n: pools[key][n].at[:, dst].set(
-                    pools[key][n][:, src]) for n in ("k", "v")}
-            else:
-                out[key] = {n: pools[key][n].at[dst].set(
-                    pools[key][n][src]) for n in ("k", "v")}
+        for key, stacked in self._paged_groups():
+            pool = pools[key]
+            out[key] = (pool.at[:, dst].set(pool[:, src]) if stacked
+                        else pool.at[dst].set(pool[src]))
         return out
 
     def decode_step_paged(self, params: PyTree, cache: PyTree,
@@ -807,29 +799,39 @@ class LM:
         x = self.embed(params, batch)
         pat = self.pattern
 
-        def body(x, inp):
-            slices, csl, psl = inp
+        # the stacked pools ride the scan's carry, not its xs/ys: each
+        # layer writes its rows into them in place and the kernel reads
+        # its pages out of them at the layer's index, so no op slices,
+        # relayouts or re-stacks a pool
+        def body(carry, inp):
+            x, psl = carry
+            slices, csl, layer = inp
             new_c, new_p = [], []
             for slot, kind in enumerate(pat):
                 x, c2, p2 = block_decode_paged(cfg, kind, slices[slot], x,
                                                pos, csl[slot], psl[slot],
-                                               tables)
+                                               layer, tables)
                 new_c.append(c2)
                 new_p.append(p2)
-            return x, (tuple(new_c), tuple(new_p))
+            return (x, tuple(new_p)), tuple(new_c)
 
         xs = tuple(params["blocks"][f"s{i}"] for i in range(len(pat)))
         cs = tuple(cache[f"s{i}"] for i in range(len(pat)))
         ps = tuple(pools[f"s{i}"] for i in range(len(pat)))
-        x, (new_c, new_p) = self._scan_units(body, x, (xs, cs, ps), unroll)
+        layer = jnp.arange(self.n_units, dtype=jnp.int32)
+        (x, new_p), new_c = self._scan_units(body, (x, ps), (xs, cs, layer),
+                                             unroll)
         out_cache = {f"s{i}": new_c[i] for i in range(len(pat))}
         out_pools = {f"s{i}": new_p[i] for i in range(len(pat))}
         for t, kind in enumerate(self.tail_kinds):
+            # a tail pool is a stack of one (a bitcast, row-major)
+            pool = pools[f"t{t}"]
             x, c2, p2 = block_decode_paged(
                 cfg, kind, params["blocks"][f"t{t}"], x, pos,
-                cache[f"t{t}"], pools[f"t{t}"], tables)
+                cache[f"t{t}"], None if pool is None else pool[None],
+                jnp.int32(0), tables)
             out_cache[f"t{t}"] = c2
-            out_pools[f"t{t}"] = p2
+            out_pools[f"t{t}"] = None if p2 is None else p2[0]
         return self._head(params, x), out_cache, out_pools
 
     # ------------------------------------------------------- streaming view

@@ -272,12 +272,16 @@ def _join_fn(model, fingerprint):
 
 @functools.lru_cache(maxsize=16)
 def _paged_step_fn(model, fingerprint):
+    """The page pools are donated: each layer writes its rows into them
+    in place, so a step holds one pool, not an input and an output.
+    The scheduler dispatches the step under its lock and hands the
+    result pools on at once, so no other thread sees a donated pool."""
     def step(params, cache, pools, tables, tok, pos, seed, temp):
         logits, cache, pools = model.decode_step_paged(
             params, cache, pools, tables, tok, pos)
         nxt = sample_tokens(logits[:, -1, :], seed, pos + 1, temp)
         return nxt[:, None], cache, pools
-    return jax.jit(step)
+    return jax.jit(step, donate_argnums=2)
 
 
 @functools.lru_cache(maxsize=16)
@@ -508,8 +512,8 @@ class DecodeScheduler:
                     off = n_hit * pt
                     if off:
                         # hit pages are pinned, so immutable; dispatched
-                        # under the lock, before any later pack donates
-                        # the pool array it reads
+                        # under the lock, before any later pack or step
+                        # donates the pool array it reads
                         with self._cv:
                             cache1 = self._gather(
                                 cache1, self._kvpages,
@@ -681,10 +685,16 @@ class DecodeScheduler:
                         seed = jnp.asarray(self._seed)
                         temp = jnp.asarray(self._temp)
                         if self.paged:
-                            pools = self._kvpages
-                            tables = jnp.asarray(self._tables)
                             pages = int((self._pos // self.page_tokens
                                          + 1).sum())
+                            # dispatched under the lock: the step
+                            # consumes the pools, and a prefix hit's
+                            # gather must read the step's output, never
+                            # the donated input
+                            nxt, new_cache, self._kvpages = self._pstep(
+                                params, cache, self._kvpages,
+                                jnp.asarray(self._tables), tok, pos, seed,
+                                temp)
                     except BaseException as e:
                         # anything failing while _stepping is set must
                         # fail ALL residents, or their threads wait
@@ -692,11 +702,7 @@ class DecodeScheduler:
                         self._fail_locked(e)
                         raise
                 try:
-                    if self.paged:
-                        nxt, new_cache, new_pools = self._pstep(
-                            params, cache, pools, tables, tok, pos, seed,
-                            temp)
-                    else:
+                    if not self.paged:
                         nxt, new_cache = self._step(params, cache, tok,
                                                     pos, seed, temp)
                     nxt_host = np.asarray(nxt)
@@ -707,8 +713,6 @@ class DecodeScheduler:
                 t_now = time.monotonic()
                 with self._cv:
                     self._cache = new_cache
-                    if self.paged:
-                        self._kvpages = new_pools
                     self.steps += 1
                     for slot in list(self._slots):
                         req = self._slots[slot]

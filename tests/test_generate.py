@@ -393,8 +393,8 @@ def test_registry_auto_probes_and_forces(monkeypatch):
     monkeypatch.delenv("REPRO_PALLAS", raising=False)
     desc = ops.registry.describe()
     assert set(desc) == {"flash_attention", "decode_attention",
-                         "decode_attention_paged", "ssd_scan",
-                         "rglru_scan", "weight_transform",
+                         "decode_attention_paged", "kv_pages_write",
+                         "ssd_scan", "rglru_scan", "weight_transform",
                          "quant_matmul"}
     if jax.default_backend() != "tpu":
         assert all(not d["compiled"] for d in desc.values())

@@ -10,6 +10,7 @@ from repro.kernels import ops, ref
 from repro.kernels.decode_attention import decode_attention as decode_pallas
 from repro.kernels.decode_attention import (decode_attention_paged
                                             as paged_pallas)
+from repro.kernels.decode_attention import kv_pages_write as kv_write_pallas
 from repro.kernels.flash_attention import flash_attention as flash_pallas
 from repro.kernels.rglru_scan import rglru_scan as rglru_pallas
 from repro.kernels.ssd_scan import ssd_scan as ssd_pallas
@@ -123,6 +124,15 @@ def test_decode_ring_buffer_semantics():
 # paged decode attention
 # ---------------------------------------------------------------------------
 
+def _stack(k_pages, v_pages, layer=1, n_layers=2):
+    """The kernels' operand: (P, K, pt, dh) K and V pools side by side in
+    lanes, as layer ``layer`` of a stack of ``n_layers`` whose other
+    layers hold garbage (a kernel that reads the wrong layer fails)."""
+    kv = jnp.concatenate([k_pages, v_pages], axis=-1)
+    junk = arr(n_layers, *kv.shape, dtype=kv.dtype)
+    return junk.at[layer].set(kv), jnp.int32(layer)
+
+
 def _paged_from_slotted(kc, vc, NP, pt, n_garbage=0):
     """Scatter a (B, K, S, dh) slotted cache into page pools + tables;
     page order is deliberately shuffled.  ``n_garbage`` extra table
@@ -141,8 +151,8 @@ def _paged_from_slotted(kc, vc, NP, pt, n_garbage=0):
             tables[b, j] = pid
             k_pages[pid] = np.asarray(kc[b, :, j * pt:(j + 1) * pt])
             v_pages[pid] = np.asarray(vc[b, :, j * pt:(j + 1) * pt])
-    return (jnp.asarray(k_pages), jnp.asarray(v_pages),
-            jnp.asarray(tables))
+    kv, layer = _stack(jnp.asarray(k_pages), jnp.asarray(v_pages))
+    return kv, layer, jnp.asarray(tables)
 
 
 @pytest.mark.parametrize("window", [0, 128])
@@ -155,11 +165,11 @@ def test_decode_paged_vs_slotted(window, pt, bs):
     kc, vc = arr(B, K, S, dh), arr(B, K, S, dh)
     pos = jnp.asarray([3, 100, 127], jnp.int32)
     o_slot = ref.decode_attention(q, kc, vc, pos, window=window)
-    k_pages, v_pages, tables = _paged_from_slotted(kc, vc, S // pt, pt)
-    o_ref = ref.decode_attention_paged(q, k_pages, v_pages, tables, pos,
+    kv, layer, tables = _paged_from_slotted(kc, vc, S // pt, pt)
+    o_ref = ref.decode_attention_paged(q, kv, tables, pos, layer,
                                        window=window)
     np.testing.assert_array_equal(np.asarray(o_ref), np.asarray(o_slot))
-    o_pal = paged_pallas(q, k_pages, v_pages, tables, pos, window=window,
+    o_pal = paged_pallas(q, kv, tables, pos, layer, window=window,
                          bs=bs, interpret=True)
     np.testing.assert_allclose(np.asarray(o_pal), np.asarray(o_slot),
                                atol=2e-5, rtol=2e-5)
@@ -173,9 +183,9 @@ def test_decode_paged_ragged_tables():
     kc, vc = arr(B, K, S, dh), arr(B, K, S, dh)
     pos = jnp.asarray([10, 63], jnp.int32)
     o_slot = ref.decode_attention(q, kc, vc, pos)
-    k_pages, v_pages, tables = _paged_from_slotted(kc, vc, S // pt, pt,
-                                                   n_garbage=2)
-    o_ref = ref.decode_attention_paged(q, k_pages, v_pages, tables, pos)
+    kv, layer, tables = _paged_from_slotted(kc, vc, S // pt, pt,
+                                            n_garbage=2)
+    o_ref = ref.decode_attention_paged(q, kv, tables, pos, layer)
     # the slotted oracle over the same extent, with different garbage in
     # the two extra pages: exact equality means masking zeroes them
     # exactly (the softmax reduces over the same length in both)
@@ -183,8 +193,7 @@ def test_decode_paged_ragged_tables():
     vx = jnp.concatenate([vc, arr(B, K, 2 * pt, dh)], axis=2)
     o_ext = ref.decode_attention(q, kx, vx, pos)
     np.testing.assert_array_equal(np.asarray(o_ref), np.asarray(o_ext))
-    o_pal = paged_pallas(q, k_pages, v_pages, tables, pos, bs=16,
-                         interpret=True)
+    o_pal = paged_pallas(q, kv, tables, pos, layer, bs=16, interpret=True)
     np.testing.assert_allclose(np.asarray(o_pal), np.asarray(o_slot),
                                atol=2e-5, rtol=2e-5)
 
@@ -196,9 +205,9 @@ def test_decode_paged_ops_dispatch():
     q = arr(B, H, dh)
     kc, vc = arr(B, K, S, dh), arr(B, K, S, dh)
     pos = jnp.asarray([7, 60], jnp.int32)
-    k_pages, v_pages, tables = _paged_from_slotted(kc, vc, S // pt, pt)
-    o_ref = ref.decode_attention_paged(q, k_pages, v_pages, tables, pos)
-    o = ops.decode_attention_paged(q, k_pages, v_pages, tables, pos)
+    kv, layer, tables = _paged_from_slotted(kc, vc, S // pt, pt)
+    o_ref = ref.decode_attention_paged(q, kv, tables, pos, layer)
+    o = ops.decode_attention_paged(q, kv, tables, pos, layer)
     np.testing.assert_allclose(np.asarray(o), np.asarray(o_ref),
                                atol=2e-5, rtol=2e-5)
 
@@ -209,8 +218,8 @@ def _live_page_case(B, H, K, dh, pt, NP, pos, dtype, scratch_rows=()):
     dead rows whose whole table is the scratch page (the last)."""
     r = np.random.default_rng(11)
     P = B * NP + 1
-    k_pages = arr(P, K, pt, dh, dtype=dtype)
-    v_pages = arr(P, K, pt, dh, dtype=dtype)
+    kv, layer = _stack(arr(P, K, pt, dh, dtype=dtype),
+                       arr(P, K, pt, dh, dtype=dtype))
     tables = r.integers(0, P, (B, NP)).astype(np.int32)
     perm = r.permutation(P - 1)
     for b in range(B):
@@ -219,8 +228,8 @@ def _live_page_case(B, H, K, dh, pt, NP, pos, dtype, scratch_rows=()):
         n_live = min(pos[b] // pt + 1, NP)
         if b not in scratch_rows and n_live < NP:
             tables[b, n_live:] = r.integers(0, P, NP - n_live)
-    return (arr(B, H, dh, dtype=dtype), k_pages, v_pages,
-            jnp.asarray(tables), jnp.asarray(pos, jnp.int32))
+    return (arr(B, H, dh, dtype=dtype), kv, layer, jnp.asarray(tables),
+            jnp.asarray(pos, jnp.int32))
 
 
 @pytest.mark.parametrize("B,H,K,dh,pt,NP,pos,window,bs,dtype,dead", [
@@ -245,14 +254,67 @@ def test_decode_paged_live_pages(B, H, K, dh, pt, NP, pos, window, bs,
                                  dtype, dead):
     """The paged kernel reads each row's live pages only: it matches the
     gather-everything oracle whatever the table holds past ``pos``."""
-    q, k_pages, v_pages, tables, pos = _live_page_case(
+    q, kv, layer, tables, pos = _live_page_case(
         B, H, K, dh, pt, NP, pos, dtype, scratch_rows=dead)
-    o_ref = ref.decode_attention_paged(q, k_pages, v_pages, tables, pos,
+    o_ref = ref.decode_attention_paged(q, kv, tables, pos, layer,
                                        window=window)
-    o = paged_pallas(q, k_pages, v_pages, tables, pos, window=window,
-                     bs=bs, interpret=True)
+    o = paged_pallas(q, kv, tables, pos, layer, window=window, bs=bs,
+                     interpret=True)
     np.testing.assert_allclose(np.asarray(o, np.float32),
                                np.asarray(o_ref, np.float32), **tol(dtype))
+
+
+@pytest.mark.parametrize("dh", [64, 128])
+@pytest.mark.parametrize("case", ["page-edges", "dead-rows", "tail"])
+def test_kv_pages_write_matches_scatter(dh, case):
+    """The aliased row write equals the scatter it replaced,
+    ``k_pages.at[pg, :, row].set(k)`` on separate K and V pools, at
+    ``pos % pt`` 0 and pt - 1, with dead rows parked on the scratch page
+    (whose content is garbage either way), and on a tail group's pool
+    (a stack of one)."""
+    K, pt, P, B = 2, 16, 9, 4
+    n_layers, layer = (1, 0) if case == "tail" else (3, 1)
+    k_pages, v_pages = (arr(P, K, pt, dh, dtype=jnp.bfloat16)
+                        for _ in range(2))
+    kv, layer = _stack(k_pages, v_pages, layer, n_layers)
+    pos = np.array([0, pt - 1, 3 * pt, 5 * pt + pt - 1])
+    pg = np.array([2, 5, 0, 7])
+    if case == "dead-rows":
+        pos[[1, 3]], pg[[1, 3]] = 0, P - 1
+    k, v = (arr(B, K, dh, dtype=jnp.bfloat16) for _ in range(2))
+    row = jnp.asarray(pos % pt, jnp.int32)
+    pg = jnp.asarray(pg, jnp.int32)
+    want = jnp.concatenate([k_pages.at[pg, :, row].set(k),
+                            v_pages.at[pg, :, row].set(v)], axis=-1)
+    new = jnp.concatenate([k, v], axis=-1)[:, :, None]      # (B, K, 1, 2dh)
+    got = kv_write_pallas(kv, layer, pg, row, new, interpret=True)
+    live = np.arange(P) != P - 1 if case == "dead-rows" else np.s_[:]
+    np.testing.assert_array_equal(np.asarray(got[layer][live], np.float32),
+                                  np.asarray(want[live], np.float32))
+    others = np.arange(n_layers) != int(layer)
+    np.testing.assert_array_equal(np.asarray(got[others], np.float32),
+                                  np.asarray(kv[others], np.float32))
+    np.testing.assert_array_equal(
+        np.asarray(ops.kv_pages_write(kv, layer, pg, row, new)[layer][live],
+                   np.float32), np.asarray(want[live], np.float32))
+
+
+def test_kv_pages_write_whole_pages():
+    """A pack's write: whole pages of several layers at once, each to its
+    own (layer, page); every other page is left as it was."""
+    L, P, K, pt, w = 3, 7, 2, 16, 128
+    kv = arr(L, P, K, pt, w)
+    layer = jnp.asarray([0, 0, 2, 1], jnp.int32)
+    pg = jnp.asarray([4, 1, 4, 6], jnp.int32)
+    pages = arr(4, K, pt, w)
+    got = kv_write_pallas(kv, layer, pg, jnp.zeros(4, jnp.int32), pages,
+                          interpret=True)
+    want = np.asarray(kv).copy()
+    for i in range(4):
+        want[int(layer[i]), int(pg[i])] = np.asarray(pages[i])
+    np.testing.assert_array_equal(np.asarray(got), want)
+    np.testing.assert_array_equal(
+        np.asarray(ref.kv_pages_write(kv, layer, pg, None, pages)), want)
 
 
 # ---------------------------------------------------------------------------

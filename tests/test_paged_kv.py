@@ -461,3 +461,101 @@ def test_pack_and_prefill_donate_their_buffers(dense):
         assert out[i].tokens == reference_generate(
             m, params, p, n_new=5, cache_len=CACHE_LEN, seed=i)
     sched.kvpool.check_invariants()
+
+
+@pytest.mark.parametrize("mode,arch", [
+    ("ref", "smollm-360m"),
+    ("interpret", "smollm-360m"),
+    # pattern (attn, rglru) over 3 layers: a paged tail group
+    ("interpret", "attn-tail"),
+])
+def test_step_consumes_the_pools_it_was_given(monkeypatch, mode, arch):
+    """A decode step donates the page pools: each layer writes its rows
+    into them in place (the Pallas write in interpret mode), so every
+    pool leaf a step was handed is deleted once it is dispatched, and
+    the scheduler holds the step's output.  A prefix hit's gather,
+    dispatched while other requests step, still reads live pools; every
+    request generates its serial reference's tokens."""
+    monkeypatch.setenv("REPRO_PALLAS", mode)
+    if arch == "attn-tail":
+        cfg = _f32_cfg("recurrentgemma-2b", block_pattern=("attn", "rglru"),
+                       n_layers=3)
+    else:
+        cfg = _f32_cfg(arch)
+    m = transformer.build(cfg)
+    assert m.tail_kinds == (("attn",) if arch == "attn-tail" else ())
+    params = m.init(jax.random.key(0))
+    sched = DecodeScheduler(m, params, n_slots=2, cache_len=CACHE_LEN,
+                            kv_page_tokens=PT, kv_max_seq=CACHE_LEN)
+    seen = []
+    step = sched._pstep
+
+    def spy(params, cache, pools, *rest):
+        seen.append(jax.tree.leaves(pools))
+        return step(params, cache, pools, *rest)
+
+    sched._pstep = spy
+    shared = _prompt(cfg, 300, n=2 * PT)
+    prompts = [np.concatenate([shared, _prompt(cfg, 301 + i, n=3 + i)])
+               for i in range(3)]
+    out = [None] * len(prompts)
+
+    def go(i):
+        out[i] = sched.generate(GenerateSpec(prompt=prompts[i], n_new=6,
+                                             seed=i))
+
+    go(0)
+    threads = [threading.Thread(target=go, args=(i,)) for i in (1, 2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join()
+    assert len(seen) >= 5 and all(seen)
+    assert all(x.is_deleted() for leaves in seen for x in leaves)
+    assert not any(x.is_deleted() for x in jax.tree.leaves(sched._kvpages))
+    if m.supports_prefix_cache:
+        assert sched.kvpool.stats().prefix_hits == 4
+    for i, p in enumerate(prompts):
+        assert out[i].tokens == reference_generate(
+            m, params, p, n_new=6, cache_len=CACHE_LEN, seed=i), i
+    sched.kvpool.check_invariants()
+
+
+@pytest.mark.parametrize("mode", ["ref", "interpret"])
+@pytest.mark.parametrize("arch", ["smollm-360m", "attn-tail"])
+def test_pack_gather_and_copy_page_round_trip(monkeypatch, mode, arch):
+    """The device halves of admission on the lane-dense pool, stacked and
+    tail groups alike: pages packed from a request's cache, forked by
+    ``copy_page`` and gathered back give that cache's rows exactly, and
+    leave every other page as it was."""
+    monkeypatch.setenv("REPRO_PALLAS", mode)
+    if arch == "attn-tail":
+        cfg = _f32_cfg("recurrentgemma-2b", block_pattern=("attn", "rglru"),
+                       n_layers=3)
+    else:
+        cfg = _f32_cfg(arch)
+    m = transformer.build(cfg)
+    n_pp = 3
+    cache = m.init_request_cache(n_pp * PT, CACHE_LEN)
+    r = np.random.default_rng(5)
+    cache = jax.tree.map(lambda a: jnp.asarray(
+        r.standard_normal(a.shape), a.dtype), cache)
+    pools = m.init_kv_pages(8, PT)
+    before = jax.tree.map(np.asarray, pools)
+    ids = jnp.asarray([6, 1, 4], jnp.int32)
+    pools = jax.jit(m.pack_pages, static_argnums=3)(pools, cache, ids[1:], 1)
+    pools = m.copy_page(pools, 4, 3)
+    back = m.gather_pages(m.init_request_cache(n_pp * PT, CACHE_LEN), pools,
+                          jnp.asarray([0, 1, 3], jnp.int32))
+    for key, kind, stacked in m._cache_groups():
+        if pools[key] is None:
+            continue
+        pool = np.asarray(pools[key] if stacked else pools[key][None])
+        untouched = [0, 2, 5, 6, 7]
+        np.testing.assert_array_equal(
+            pool[:, untouched],
+            (before[key] if stacked else before[key][None])[:, untouched])
+        for n in ("k", "v"):
+            want = np.asarray(cache[key][n])[..., PT:, :]    # pages 1, 2
+            got = np.asarray(back[key][n])[..., PT:, :]
+            np.testing.assert_array_equal(got, want)

@@ -8,7 +8,9 @@ topology is described inside a fixture, so only the worker that runs
 this file loads the TPU compiler; where it cannot be described, every
 test here skips.
 """
+import dataclasses
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -98,8 +100,8 @@ def test_decode_attention_compiles(one_chip, B, S_max):
 def test_decode_attention_paged_compiles(one_chip, B, pages, NP):
     pt = 16
     _compile(decode_attention_paged, one_chip,
-             [((B, H, DH), BF), ((pages, K, pt, DH), BF),
-              ((pages, K, pt, DH), BF), ((B, NP), I32), ((B,), I32)],
+             [((B, H, DH), BF), ((2, pages, K, pt, 2 * DH), BF),
+              ((B, NP), I32), ((B,), I32), ((), I32)],
              window=0, bs=KB.decode_bs)
 
 
@@ -152,7 +154,66 @@ def test_yi_widths_compile(one_chip, kernel):
     else:
         pages, pt = 4578, 16
         _compile(decode_attention_paged, one_chip,
-                 [((64, YI_H, YI_DH), BF), ((pages, YI_K, pt, YI_DH), BF),
-                  ((pages, YI_K, pt, YI_DH), BF), ((64, 160), I32),
-                  ((64,), I32)],
+                 [((64, YI_H, YI_DH), BF),
+                  ((2, pages, YI_K, pt, 2 * YI_DH), BF), ((64, 160), I32),
+                  ((64,), I32), ((), I32)],
                  window=0, bs=KB.decode_bs)
+
+
+# ops that would copy, slice, relayout or scatter a whole page pool
+POOL_OPS = ("copy", "copy-start", "dynamic-slice", "dynamic-update-slice",
+            "scatter", "fusion")
+
+
+@pytest.mark.parametrize("program,arch,pages", [
+    ("step", "smollm-360m", 2289),   # the chat cell's pool, head size 64
+    ("step", "yi-9b", 4578),         # the yi batch cell's, head size 128
+    ("pack", "smollm-360m", 2289),
+])
+def test_paged_programs_keep_the_pool_in_place(one_chip, monkeypatch,
+                                               program, arch, pages):
+    """The decode step (64 rows, 160-page tables) and the admission pack
+    (a 2048-token prompt) of a two-layer model at published widths, as
+    the scheduler jits them: no op of either copies, slices, relayouts
+    or scatters an array with the pool's page count as a dimension, and
+    the donated pool is aliased to the output, so each writes its rows
+    or pages into the pool where it lies."""
+    from repro.models import transformer
+    from repro.models.api import get_config
+    from repro.serving import decode
+    monkeypatch.setenv("REPRO_PALLAS", "pallas")
+    m = transformer.build(dataclasses.replace(get_config(arch), n_layers=2))
+    B, NP, pt = 64, 160, 16
+
+    def place(tree):
+        return jax.tree.map(lambda a: jax.ShapeDtypeStruct(
+            a.shape, a.dtype, sharding=one_chip), jax.eval_shape(tree))
+
+    def arg(shape, dtype=I32):
+        return jax.ShapeDtypeStruct(shape, dtype, sharding=one_chip)
+
+    pools = place(lambda: m.init_kv_pages(pages, pt))
+    fp = ops.registry.fingerprint()
+    if program == "step":
+        lowered = decode._paged_step_fn(m, fp).lower(
+            place(lambda: m.init(jax.random.key(0))),
+            place(lambda: m.init_cache_paged(B, 2560)), pools,
+            arg((B, NP)), arg((B, 1)), arg((B,)), arg((B,), jnp.uint32),
+            arg((B,), F32))
+    else:
+        lowered = decode._pack_fn(m, fp).lower(
+            pools, place(lambda: m.init_request_cache(2048, 2560)),
+            arg((2048 // pt,)), 0)
+    compiled = lowered.compile()
+    hlo = compiled.as_text()
+    found = []
+    for line in hlo.splitlines():
+        got = re.match(r"\s*(?:ROOT )?%\S+ = (.+?) ([\w-]+)\(", line)
+        if (got and got.group(2) in POOL_OPS
+                and re.search(rf"[\[,]{pages}[,\]]", got.group(1))):
+            found.append(line.strip()[:160])
+    assert not found, "\n".join(found)
+    pool_bytes = sum(a.size * a.dtype.itemsize
+                     for a in jax.tree.leaves(pools))
+    assert "input_output_alias={" in hlo
+    assert compiled.memory_analysis().alias_size_in_bytes == pool_bytes
