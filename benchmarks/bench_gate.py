@@ -60,14 +60,9 @@ GATES: Dict[str, Dict[str, Tuple[str, float]]] = {
         # resident bytes, expressed as f32/quant >= 1.66)
         "generate/quant/tok_s_vs_f32": ("floor", 0.9),
         "generate/quant/resident_ratio": ("floor", 1.66),
-        # block-paged KV serving (--compute-paged), baseline-
-        # independent: the paged decode kernel + page bookkeeping must
-        # hold decode throughput, a prefix-cache hit must skip enough
-        # prefill to halve TTFT, and a prompt past the slotted per-slot
-        # arena must admit under the same byte budget
-        "generate/paged/tok_s_vs_slotted": ("floor", 0.9),
+        # KV-page serving (--compute-paged), baseline-independent: a
+        # prefix-cache hit must skip enough prefill to halve TTFT
         "generate/paged/prefix_ttft_speedup": ("floor", 2.0),
-        "generate/paged/long_prompt_admitted": ("floor", 1.0),
     },
     "slo": {
         "slo/autoscale/ttft_p50_ms": ("lower", DEFAULT_TOL),

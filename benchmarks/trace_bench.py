@@ -351,23 +351,14 @@ def _quant_generate_rows(args, cfg, model, name, store, spec, n_req,
 
 def _paged_generate_rows(args, cfg, model, name, store, spec, n_req,
                          cache_len):
-    """--compute-paged rows: block-paged KV serving vs the slotted path.
+    """--compute-paged rows: KV-page serving and its prefix cache.
 
     Rows (name, value, derived):
       generate/paged/tok_s             conc8 aggregate tokens/s through
-                                       a paged-KV instance (16-token
-                                       pages, default byte budget ==
-                                       the slotted arena's capacity);
+                                       an instance with 16-token pages
+                                       (default byte budget: every
+                                       slot's cache_len positions);
                                        derived = max slot occupancy
-      generate/paged/tok_s_vs_slotted  ratio vs a *contemporaneous*
-                                       slotted twin — rounds interleave
-                                       slotted/paged so host-load drift
-                                       cancels (the earlier conc8
-                                       number ran minutes before);
-                                       derived = the twin's tokens/s.
-                                       Gated >= 0.9: the paged decode
-                                       kernel + page bookkeeping must
-                                       not tank decode throughput
       generate/paged/prefix_ttft_ms    TTFT of a request whose 960-token
                                        prefix is already resident in
                                        the prefix cache (prefill covers
@@ -379,35 +370,22 @@ def _paged_generate_rows(args, cfg, model, name, store, spec, n_req,
                                        win of skipping shared-prefix
                                        prefill); derived = cumulative
                                        prefix-hit pages
-      generate/paged/long_prompt_admitted
-                                       1.0 when a prompt longer than the
-                                       slotted per-slot arena admits and
-                                       completes under the *same* byte
-                                       budget (pages flex across mixed
-                                       lengths); derived = pages needed
     """
     rows = []
 
-    def build(cl, pt, *, slots=8, budget=None):
+    def build(cl, pt, *, slots=8):
         return ServerlessPlatform(
             store, {name: (lambda: (model, common.make_batch(cfg)))},
             strategy="cicada", keep_alive_s=1e9, max_instances=1,
-            gen_slots=slots, gen_cache_len=cl,
-            kv_page_tokens=pt, kv_budget_bytes=budget)
+            gen_slots=slots, gen_cache_len=cl, kv_page_tokens=pt)
 
-    # ---- conc8 tokens/s: paged decode vs a contemporaneous slotted twin ---
-    sp = ServerlessPlatform(
-        store, {name: (lambda: (model, common.make_batch(cfg)))},
-        strategy="cicada", keep_alive_s=1e9, max_instances=1,
-        gen_slots=8, gen_cache_len=cache_len)
+    # ---- conc8 tokens/s ---------------------------------------------------
     pp = build(cache_len, 16)
-    s_router = sp.router(workers=8)
     p_router = pp.router(workers=8)
-    s_tok_s = p_tok_s = 0.0
+    p_tok_s = 0.0
     try:
-        for router in (s_router, p_router):             # cold + jit warm
-            router.submit(Request(req_id=-1, model=name,
-                                  gen=spec())).result()
+        p_router.submit(Request(req_id=-1, model=name,         # cold + jit
+                                gen=spec())).result()
         inst = pp.pools[name]._instances[0]
         inst.scheduler.reset_peaks()
 
@@ -420,18 +398,12 @@ def _paged_generate_rows(args, cfg, model, name, store, spec, n_req,
             return sum(r.n_generated for r in rs) / \
                 (time.monotonic() - t0)
 
-        # interleave slotted/paged rounds so host-load drift hits both
-        # sides of the ratio equally; best-of-two each
-        for rnd in range(2):
-            s_tok_s = max(s_tok_s, round_(s_router, rnd))
+        for rnd in range(2):                            # best of two
             p_tok_s = max(p_tok_s, round_(p_router, rnd))
     finally:
-        s_router.shutdown()
         p_router.shutdown()
     occ = inst.scheduler.stats()["max_occupancy"]
     rows.append(["generate/paged/tok_s", p_tok_s, float(occ)])
-    rows.append(["generate/paged/tok_s_vs_slotted",
-                 p_tok_s / s_tok_s, s_tok_s])
 
     # ---- prefix-cache TTFT: shared 960-token prefix, 64-token suffix ------
     pt2, n_pfx, n_sfx = 64, 960, 64
@@ -473,23 +445,6 @@ def _paged_generate_rows(args, cfg, model, name, store, spec, n_req,
     rows.append(["generate/paged/prefix_ttft_ms", warm_ms, cold_ms])
     rows.append(["generate/paged/prefix_ttft_speedup", cold_ms / warm_ms,
                  float(hits)])
-
-    # ---- mixed admission: beyond the per-slot arena, same byte budget -----
-    pt3 = 16
-    budget = 8 * (-(-cache_len // pt3)) * model.kv_page_bytes(pt3)
-    mp = build(2 * cache_len, pt3, budget=budget)
-    long_prompt = np.random.default_rng(11).integers(
-        0, cfg.vocab_size, (cache_len + cache_len // 2,)).astype(np.int32)
-    router = mp.router(workers=1)
-    try:
-        r = router.submit(Request(req_id=0, model=name,
-                                  gen=GenerateSpec(prompt=long_prompt,
-                                                   n_new=8))).result()
-    finally:
-        router.shutdown()
-    need = -(-(long_prompt.shape[0] + 8) // pt3)
-    rows.append(["generate/paged/long_prompt_admitted",
-                 1.0 if r.n_generated == 8 else 0.0, float(need)])
     return rows
 
 
@@ -981,12 +936,10 @@ def main(argv=None):
                          "dequant quant_matmul), reporting tokens/s vs "
                          "f32 and the resident-bytes ratio")
     ap.add_argument("--compute-paged", action="store_true",
-                    help="--workload generate: add block-paged KV "
-                         "serving rows — tokens/s vs the slotted arena, "
-                         "prefix-cache TTFT speedup on a shared "
-                         "960-token prefix, and mixed-length admission "
-                         "beyond the per-slot ceiling under the same "
-                         "byte budget")
+                    help="--workload generate: add KV-page serving "
+                         "rows — conc8 tokens/s with 16-token pages and "
+                         "the prefix-cache TTFT speedup on a shared "
+                         "960-token prefix")
     ap.add_argument("--pallas", default=None,
                     choices=["auto", "pallas", "interpret", "ref"],
                     help="force the kernel dispatch registry (default: "

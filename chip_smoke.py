@@ -7,8 +7,7 @@
 One chip: deploys full-width smollm-360m (random weights made from
 ``--seed``) to a weight store inside the checkout and serves it through
 ServerlessPlatform -> Router -> InstancePool -> ColdStartEngine ->
-DecodeScheduler, once with the slotted KV cache and once with 16-token
-KV pages.  It checks:
+DecodeScheduler, its KV in 16-token pages.  It checks:
 
   * the logits of a one-shot cold request, computed inside the loading
     pipeline, against a plain float32 forward of the same parameters
@@ -41,7 +40,6 @@ check fails, the script exits non-zero and prints no such line.
 from __future__ import annotations
 
 import argparse
-import gc
 import itertools
 import json
 import os
@@ -260,9 +258,8 @@ class Smoke:
         ``tokens``, (n, V) float32.
 
         ``rows > 1`` runs the decode steps at batch ``rows`` with the
-        request in row 0, as the scheduler's slotted arena does; the
-        other rows are empty, or (``fill_rows``) hold the same request
-        at the same position."""
+        request in row 0; the other rows are empty, or (``fill_rows``)
+        hold the same request at the same position."""
         S = len(prompt)
         c1 = self.model.init_cache(1, self.cache_len)
         lg, c1 = self._prefill(params, {"tokens": jnp.asarray(prompt[None])},
@@ -422,9 +419,8 @@ def check_generation(c: Checks, s: Smoke, plat, router, label: str):
     c.check(f"{label}: warm requests joined one batch",
             all(not r.cold for r in resps[1:]) and st["max_occupancy"] >= 2,
             f"join-wave max occupancy {st['max_occupancy']}")
-    if "kv_prefix_hits" in st:
-        c.check(f"{label}: shared prefix served from cached pages",
-                st["kv_prefix_hits"] >= 2, f"hits {st['kv_prefix_hits']}")
+    c.check(f"{label}: shared prefix served from cached pages",
+            st["kv_prefix_hits"] >= 2, f"hits {st['kv_prefix_hits']}")
     toks = [[int(t) for t in r.tokens] for r in resps]
     c.check(f"{label}: same greedy request bit-identical at another "
             f"batch occupancy", toks[-3] == toks[4],
@@ -488,17 +484,13 @@ def check_batch_shape(c: Checks, s: Smoke, params, tokens):
 
 
 def run_single(c: Checks, s: Smoke):
-    plat = s.platform()
+    plat = s.platform(kv_page_tokens=s.page_tokens)
     with plat.router(workers=4) as router:
         check_oneshot_cold(c, s, plat, router)
         plat.sweep(1e9)                # keep-alive lapsed: cold again
-        toks = check_generation(c, s, plat, router, "slotted")
+        toks = check_generation(c, s, plat, router,
+                                f"paged pt={s.page_tokens}")
         check_batch_shape(c, s, _instance(plat, s.cfg.name).params, toks)
-    del plat
-    gc.collect()                       # free the first instance's params
-    plat = s.platform(kv_page_tokens=s.page_tokens)
-    with plat.router(workers=4) as router:
-        check_generation(c, s, plat, router, f"paged pt={s.page_tokens}")
 
 
 def check_kernels(c: Checks, expect: str, before,

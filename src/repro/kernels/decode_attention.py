@@ -267,7 +267,7 @@ def decode_attention_paged(q: jax.Array, kv_pages: jax.Array,
     The grid has one step per chunk of a row's live pages, rows in
     order; a chunk is ``bs // (K * pt)`` pages (at least one), so a step
     holds about ``bs`` keys over all KV heads.  Ring-window semantics
-    are the slotted kernel's over the logical extent.
+    are the contiguous-cache kernel's over the logical extent.
     """
     B, H, dh = q.shape
     K, pt, w = kv_pages.shape[2], kv_pages.shape[3], kv_pages.shape[4]

@@ -109,7 +109,8 @@ def decode_attention_paged(q: jax.Array, kv_pages: jax.Array,
     The definition: per batch row, gather its NP pages into the
     contiguous logical cache and run :func:`decode_attention` — so the
     math (masking of garbage rows beyond ``pos``, ring-window validity
-    over logical positions, GQA) is *identical* to the slotted oracle.
+    over logical positions, GQA) is *identical* to the contiguous-cache
+    oracle.
     """
     B, dh = q.shape[0], q.shape[2]
     K, pt = kv_pages.shape[2], kv_pages.shape[3]

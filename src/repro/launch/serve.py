@@ -116,19 +116,17 @@ def main(argv=None):
                     help="decode-scheduler slots per instance "
                          "(max concurrent generations batching)")
     ap.add_argument("--gen-cache-len", type=int, default=256,
-                    help="KV cache positions per slot")
-    ap.add_argument("--kv-page-tokens", type=int, default=None,
+                    help="most positions (prompt + generated) one "
+                         "generation request may hold")
+    ap.add_argument("--kv-page-tokens", type=int, default=16,
                     metavar="PT",
-                    help="enable block-paged decode KV: full-attention "
-                         "K/V lives in a shared refcounted pool of "
-                         "PT-token pages (page-budget admission + "
-                         "prefix caching) instead of per-slot arena "
-                         "rows (default: slotted)")
+                    help="decode KV page size: full-attention K/V lives "
+                         "in a shared refcounted pool of PT-token pages "
+                         "(page-budget admission + prefix caching)")
     ap.add_argument("--kv-budget-mb", type=float, default=None,
-                    help="with --kv-page-tokens: device byte budget for "
-                         "the page pool across all attention layers "
-                         "(default: the slotted arena's worth, "
-                         "gen-slots x gen-cache-len tokens)")
+                    help="device byte budget for the KV page pool across "
+                         "all attention layers (default: gen-slots x "
+                         "gen-cache-len tokens' worth)")
     ap.add_argument("--temperature", type=float, default=0.0,
                     help="0 = greedy; >0 = sampled generation")
     ap.add_argument("--cache-budget-mb", type=float, default=None,
@@ -217,14 +215,13 @@ def main(argv=None):
 
     cache_budget = None if args.cache_budget_mb is None \
         else int(args.cache_budget_mb * 1e6)
+    kv_budget = None if args.kv_budget_mb is None \
+        else int(args.kv_budget_mb * 1e6)
     is_cluster = args.nodes > 1
     if is_cluster:
         if args.autoscale:
             raise SystemExit("--autoscale is a per-node policy; not "
                              "supported with --nodes > 1")
-        if args.kv_page_tokens:
-            raise SystemExit("--kv-page-tokens is per-node scheduler "
-                             "state; not yet plumbed with --nodes > 1")
         from repro.cluster import ClusterPlatform
         # the peer tier requires per-node caches: default unbounded
         platform = ClusterPlatform(
@@ -234,6 +231,7 @@ def main(argv=None):
             strategy=args.strategy, keep_alive_s=args.keep_alive,
             max_instances=args.max_instances, gen_slots=args.gen_slots,
             gen_cache_len=args.gen_cache_len,
+            kv_page_tokens=args.kv_page_tokens, kv_budget_bytes=kv_budget,
             mesh_shape=(1, args.mesh) if args.mesh > 1 else None)
     else:
         platform = ServerlessPlatform(
@@ -244,8 +242,7 @@ def main(argv=None):
             gen_slots=args.gen_slots,
             gen_cache_len=args.gen_cache_len,
             kv_page_tokens=args.kv_page_tokens,
-            kv_budget_bytes=None if args.kv_budget_mb is None
-            else int(args.kv_budget_mb * 1e6),
+            kv_budget_bytes=kv_budget,
             mesh_shape=(1, args.mesh) if args.mesh > 1 else None,
             compute_quant=args.compute_quant,
             autoscale=dict(rps_per_instance=args.rps_per_instance)

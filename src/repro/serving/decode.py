@@ -1,29 +1,36 @@
-"""Slot-based continuous-batching decode engine.
+"""Continuous-batching decode over a shared pool of KV pages.
 
 One :class:`DecodeScheduler` is owned by each live
-:class:`~repro.serving.pool.FunctionInstance`.  It holds a single
-fixed-capacity *slotted* KV cache — ``init_cache(n_slots, cache_len)``
-— and decodes every resident generation request with one shared jitted
-step, whatever the slot occupancy:
+:class:`~repro.serving.pool.FunctionInstance`.  Full-attention K/V lives
+in one refcounted pool of ``kv_page_tokens``-position pages
+(:class:`~repro.serving.kvpages.KVPagePool` for the bookkeeping, one
+device array per paged layer group); each batch row reads its pages
+through a row of the page table.  Ring-window, SSM and RG-LRU state stays
+slot-resident (``init_cache_paged(n_slots, cache_len)``).  Every resident
+request decodes through one shared jitted step, whatever the occupancy:
 
-  * a request **joins** at a step boundary: its prompt is prefilled into
-    a fresh ``B=1`` cache on the calling thread, then merged into a free
-    slot between two batch steps (an in-flight step never observes a
-    half-written slot);
-  * a request **leaves** on completion or EOS, freeing its slot for the
-    next joiner — requests arriving at different times batch dynamically
-    instead of serializing;
+  * a request **joins** at a step boundary: it reserves its whole page
+    span up front (prefix hits first, the rest all-or-nothing — blocking
+    backpressure, never a per-slot arena), its prompt suffix is prefilled
+    into a fresh ``B=1`` cache on the calling thread, and between two
+    batch steps the prompt pages are packed into the pool and the
+    slot-resident state merged into a free slot (an in-flight step never
+    observes a half-written slot);
+  * a request **leaves** on completion or EOS, releasing its pages and
+    its slot for the next joiner — requests arriving at different times
+    batch dynamically instead of serializing;
   * the batched step is **cooperatively driven**: every caller thread
     blocked in :meth:`generate` is eligible to run the next step, so the
     engine needs no dedicated decode thread and quiesces for free when
     no request is resident.
 
-Correctness invariant (enforced by tests/test_generate.py): each
-request's token sequence is *bit-identical* to :func:`reference_generate`
-— a serial ``prefill`` + ``decode_step`` loop at ``B=1`` — because every
-per-slot computation (attention over its own cache rows, per-row MoE
-dispatch, SSM/RG-LRU state updates, sampling keyed by seed+position) is
-independent of what the other slots hold.
+Correctness invariant (enforced by tests/test_generate.py and
+tests/test_paged_kv.py): each request's token sequence is *bit-identical*
+to :func:`reference_generate` — a serial ``prefill`` + ``decode_step``
+loop at ``B=1`` over a contiguous cache — because every per-row
+computation (attention over its own pages, per-row MoE dispatch,
+SSM/RG-LRU state updates, sampling keyed by seed+position) is
+independent of what the other rows hold.
 """
 from __future__ import annotations
 
@@ -83,13 +90,8 @@ def sample_first(logits, spec: GenerateSpec, n_prompt: int) -> int:
         jnp.asarray([spec.temperature], jnp.float32))[0])
 
 
-def validate_spec(spec: GenerateSpec, n_prompt: int, cache_len: int) -> int:
-    """Clamp n_new to the per-request max_len and validate against the
-    KV cache capacity; returns the effective n_new.
-
-    This replaces the old ``BatchedLMServer.generate`` behaviour of
-    silently wrapping/dropping KV entries once S + n_new overran
-    cache_len."""
+def _clamp_n_new(spec: GenerateSpec, n_prompt: int) -> int:
+    """``spec.n_new`` clamped to the per-request ``max_len``."""
     n_new = int(spec.n_new)
     if n_new < 1:
         raise ValueError(f"n_new must be >= 1, got {spec.n_new}")
@@ -99,32 +101,70 @@ def validate_spec(spec: GenerateSpec, n_prompt: int, cache_len: int) -> int:
             raise CacheOverflowError(
                 f"max_len={spec.max_len} leaves no room to generate "
                 f"after a {n_prompt}-token prompt")
+    return n_new
+
+
+def _check_cache_len(n_prompt: int, n_new: int, cache_len: int):
     if n_prompt + n_new > cache_len:
         raise CacheOverflowError(
             f"prompt ({n_prompt}) + n_new ({n_new}) = {n_prompt + n_new} "
             f"tokens overflow the decode cache (cache_len={cache_len}); "
             f"lower n_new / set max_len <= {cache_len} or provision a "
             f"larger cache")
-    return n_new
 
 
-def validate_spec_paged(spec: GenerateSpec, n_prompt: int, *,
-                        page_tokens: int, n_pages: int,
-                        stats: Optional["kvpages.KVPageStats"] = None) -> int:
-    """Paged-mode admission check: the only *error* is a request that
-    could never fit the page budget (everything smaller is blocking
-    backpressure in the pool, not an exception).  Returns the effective
-    n_new.  ``n_pages`` is the per-request page ceiling — min(pool
-    budget, page-table width)."""
-    n_new = int(spec.n_new)
-    if n_new < 1:
-        raise ValueError(f"n_new must be >= 1, got {spec.n_new}")
-    if spec.max_len is not None:
-        n_new = min(n_new, int(spec.max_len) - n_prompt)
-        if n_new < 1:
-            raise CacheOverflowError(
-                f"max_len={spec.max_len} leaves no room to generate "
-                f"after a {n_prompt}-token prompt")
+def paged_page_count(model, *, page_tokens: int,
+                     budget_bytes: Optional[int] = None,
+                     n_slots: int = 8, cache_len: int = 256) -> int:
+    """Page budget for a scheduler: ``budget_bytes`` divided by the
+    per-page device footprint across all paged layers, else (no byte
+    budget, or a model with no paged layers — pure-SSM/ring states cost
+    no page bytes) enough pages for every slot to hold ``cache_len``
+    positions."""
+    per_page = model.kv_page_bytes(page_tokens)
+    if budget_bytes and per_page > 0:
+        n = int(budget_bytes) // per_page
+        if n < 1:
+            raise ValueError(
+                f"kv budget {budget_bytes} B below one page "
+                f"({per_page} B across paged layers)")
+        return n
+    return n_slots * (-(-cache_len // page_tokens))
+
+
+def _page_ceiling(n_pages: int, cache_len: int, page_tokens: int) -> int:
+    """Per-request page ceiling, the page table's width: the pool, or
+    ``cache_len`` positions' worth of pages if fewer."""
+    return min(n_pages, -(-cache_len // page_tokens))
+
+
+def _all_paged(model) -> bool:
+    """Every layer's sequence state lives in pages (no ring, SSM or
+    RG-LRU state held per slot)."""
+    kinds = model.paged_kinds()
+    return bool(kinds) and all(
+        k in kinds for k in set(model.pattern) | set(model.tail_kinds))
+
+
+def validate_spec(model, spec: GenerateSpec, n_prompt: int, *,
+                  n_slots: int, cache_len: int, page_tokens: int,
+                  budget_bytes: Optional[int] = None,
+                  stats: Optional["kvpages.KVPageStats"] = None) -> int:
+    """Admission check of one request against a scheduler of this
+    shape; returns the effective n_new (clamped to ``max_len``).
+
+    The only *errors* are requests that could never fit: more pages
+    than the per-request ceiling, or, for a model that keeps some
+    sequence state per slot (ring window, SSM, RG-LRU), more than
+    ``cache_len`` positions.  A request that fits but finds the pool
+    busy waits for pages instead.  ``stats`` (the live pool's) only
+    adds its occupancy to the message."""
+    n_new = _clamp_n_new(spec, n_prompt)
+    n_pages = _page_ceiling(
+        paged_page_count(model, page_tokens=page_tokens,
+                         budget_bytes=budget_bytes, n_slots=n_slots,
+                         cache_len=cache_len),
+        cache_len, page_tokens)
     need = -(-(n_prompt + n_new) // page_tokens)
     if need > n_pages:
         occ = ""
@@ -135,28 +175,11 @@ def validate_spec_paged(spec: GenerateSpec, n_prompt: int, *,
             f"prompt ({n_prompt}) + n_new ({n_new}) needs {need} KV pages "
             f"but the per-request budget is {n_pages} pages x "
             f"{page_tokens} tokens = {n_pages * page_tokens} tokens{occ}; "
-            f"lower n_new / set max_len or raise the page budget "
-            f"(--kv-budget-mb)")
+            f"lower n_new / set max_len, or raise cache_len ({cache_len}) "
+            f"or the page budget (--kv-budget-mb)")
+    if not _all_paged(model):
+        _check_cache_len(n_prompt, n_new, cache_len)
     return n_new
-
-
-def paged_page_count(model, *, page_tokens: int,
-                     budget_bytes: Optional[int] = None,
-                     n_slots: int = 8, cache_len: int = 256) -> int:
-    """Page budget for a scheduler: ``budget_bytes`` divided by the
-    per-page device footprint across all paged layers, else (no byte
-    budget, or a model with no paged layers — pure-SSM/ring states cost
-    no page bytes) the slotted arena's worth of pages, so paged mode
-    never regresses capacity by default."""
-    per_page = model.kv_page_bytes(page_tokens)
-    if budget_bytes and per_page > 0:
-        n = int(budget_bytes) // per_page
-        if n < 1:
-            raise ValueError(
-                f"kv budget {budget_bytes} B below one page "
-                f"({per_page} B across paged layers)")
-        return n
-    return n_slots * (-(-cache_len // page_tokens))
 
 
 def _as_prompt(prompt) -> jax.Array:
@@ -203,9 +226,9 @@ class _Active:
         self.remaining = n_new - 1
         self.done = False
         self.error: Optional[BaseException] = None
-        # paged mode only: reserved physical pages (prefix hits first),
-        # how many of them were prefix hits, and the prompt's running
-        # page hashes (for publishing after the pack)
+        # reserved physical pages (prefix hits first), how many of them
+        # were prefix hits, and the prompt's running page hashes (for
+        # publishing after the pack)
         self.page_ids: List[int] = []
         self.n_hit = 0
         self.hashes: List[str] = []
@@ -234,28 +257,12 @@ def _prefill_fn(model, fingerprint):
 
 
 @functools.lru_cache(maxsize=16)
-def _step_fn(model, fingerprint):
-    """Jitted batched decode step + sampling, shared across every
-    scheduler of the same (model, dispatch) — same caching rationale as
-    :func:`_prefill_fn`.  Sharing matters for serving: schedulers are
-    rebuilt on every cold start and prewarm, and a per-scheduler
-    ``jax.jit`` closure both recompiled the step on each fresh
-    instance's first generation (~seconds of on-path latency that no
-    amount of pre-provisioning could hide) and leaked one pinned
-    executable per instance lifetime into the global pjit cache."""
-    def step(params, cache, tok, pos, seed, temp):
-        logits, cache = model.decode_step(params, cache, tok, pos)
-        nxt = sample_tokens(logits[:, -1, :], seed, pos + 1, temp)
-        return nxt[:, None], cache
-    return jax.jit(step)
-
-
-@functools.lru_cache(maxsize=16)
 def _join_fn(model, fingerprint):
-    """Jitted slot-merge (B=1 prefilled cache -> batch row ``slot``),
-    shared like :func:`_step_fn`.  Top-level keys distinguish the
-    stacked pattern groups ('s*': leaves are (n_units, B, ...)) from
-    tail layers ('t*': leaves are (B, ...))."""
+    """Jitted slot-merge of the slot-resident state (B=1 prefilled
+    cache -> batch row ``slot``), shared like :func:`_prefill_fn`.
+    Top-level keys distinguish the stacked pattern groups ('s*': leaves
+    are (n_units, B, ...)) from tail layers ('t*': leaves are (B, ...));
+    paged groups' ``None`` leaves carry nothing."""
     def join(cache, one, slot):
         out = {}
         for k, big in cache.items():
@@ -267,12 +274,17 @@ def _join_fn(model, fingerprint):
     return jax.jit(join)
 
 
-# paged-mode twins of the factories above — same caching and fresh-closure
-# rationale (never jit a bound method: R5)
-
 @functools.lru_cache(maxsize=16)
-def _paged_step_fn(model, fingerprint):
-    """The page pools are donated: each layer writes its rows into them
+def _step_fn(model, fingerprint):
+    """Jitted batched decode step + sampling, shared across every
+    scheduler of the same (model, dispatch) — same caching and
+    fresh-closure rationale as :func:`_prefill_fn` (never jit a bound
+    method: R5).  Sharing matters for serving: schedulers are rebuilt on
+    every cold start and prewarm, and a per-scheduler ``jax.jit``
+    closure would recompile the step on each fresh instance's first
+    generation and leak one pinned executable per instance lifetime.
+
+    The page pools are donated: each layer writes its rows into them
     in place, so a step holds one pool, not an input and an output.
     The scheduler dispatches the step under its lock and hands the
     result pools on at once, so no other thread sees a donated pool."""
@@ -282,6 +294,11 @@ def _paged_step_fn(model, fingerprint):
         nxt = sample_tokens(logits[:, -1, :], seed, pos + 1, temp)
         return nxt[:, None], cache, pools
     return jax.jit(step, donate_argnums=2)
+
+
+# the benchmark's test fixture (bench/tests/conftest.py) clears the
+# step's cache under this name too
+_paged_step_fn = _step_fn
 
 
 @functools.lru_cache(maxsize=16)
@@ -310,82 +327,68 @@ def _pack_fn(model, fingerprint):
 
 
 class DecodeScheduler:
-    """Continuous-batching decode over one slotted KV cache.
+    """Continuous-batching decode over one shared pool of KV pages.
 
     Thread-safe: any number of threads may call :meth:`generate`
     concurrently; their requests share the batched step.  ``n_slots``
-    bounds concurrent residency (the honored successor of the old
-    server's dead ``max_batch`` knob) — an (n_slots+1)-th caller blocks
-    until a slot frees, which continuous batching makes soon and often.
+    bounds concurrent residency (the batch rows) — an (n_slots+1)-th
+    caller blocks until a slot frees, which continuous batching makes
+    soon and often.  ``kv_page_tokens`` positions make a page;
+    ``kv_budget_bytes`` sizes the pool (default: every slot's
+    ``cache_len`` positions' worth).  ``cache_len`` caps one request's
+    positions, and sizes the slot-resident state.
 
     The jitted prefill and decode step trace the model's attention
     through the kernel registry (:mod:`repro.kernels.ops`): on a TPU
-    backend serving runs the ``flash_attention`` / ``decode_attention``
-    Pallas kernels the tests verify; elsewhere the ``ref`` fallback (or
-    the ``REPRO_PALLAS``/``--pallas`` forced mode) is baked in at trace
+    backend serving runs the ``flash_attention`` /
+    ``decode_attention_paged`` / ``kv_pages_write`` Pallas kernels the
+    tests verify; elsewhere the ``ref`` fallback (or the
+    ``REPRO_PALLAS``/``--pallas`` forced mode) is baked in at trace
     time — :attr:`kernel_modes` records the resolution this scheduler
     was built under.
     """
 
     def __init__(self, model, params: PyTree, *, n_slots: int = 8,
                  cache_len: int = 256,
-                 kv_page_tokens: Optional[int] = None,
+                 kv_page_tokens: int = 16,
                  kv_budget_bytes: Optional[int] = None,
-                 kv_max_seq: Optional[int] = None,
                  metrics: Optional[metrics_mod.MetricsRegistry] = None):
         if n_slots < 1:
             raise ValueError(f"n_slots must be >= 1, got {n_slots}")
         if cache_len < 2:
             raise ValueError(f"cache_len must be >= 2, got {cache_len}")
+        if kv_page_tokens is None or int(kv_page_tokens) < 1:
+            raise ValueError(
+                f"kv_page_tokens must be >= 1, got {kv_page_tokens}")
         self.model = model
         self.params = params
         self.n_slots = int(n_slots)
         self.cache_len = int(cache_len)
-        # paged mode: full-attention KV lives in a shared page pool
-        # (kvpages.KVPagePool bookkeeping + init_kv_pages device arrays)
-        # instead of per-slot arena rows; admission is page-budgeted
-        self.paged = kv_page_tokens is not None
+        self.page_tokens = pt = int(kv_page_tokens)
+        self.kv_budget_bytes = kv_budget_bytes
         m = metrics_mod.resolve(metrics)
-        if self.paged:
-            pt = int(kv_page_tokens)
-            if pt < 1:
-                raise ValueError(
-                    f"kv_page_tokens must be >= 1, got {kv_page_tokens}")
-            self.page_tokens = pt
-            self.n_pages = paged_page_count(
-                model, page_tokens=pt, budget_bytes=kv_budget_bytes,
-                n_slots=self.n_slots, cache_len=self.cache_len)
-            # page-table width == per-request page ceiling; it bounds
-            # the logical attention extent NP*pt — and with it
-            # fallback-mode gather traffic — so it defaults to the
-            # slotted cache_len rather than the whole pool.  Pass
-            # kv_max_seq > cache_len to let one request stretch across
-            # more of the page budget than a slotted arena row held.
-            self.np_max = max(1, min(
-                self.n_pages,
-                -(-int(kv_max_seq if kv_max_seq is not None
-                       else self.cache_len) // pt)))
-            self.kvpool = kvpages.KVPagePool(
-                n_pages=self.n_pages, page_tokens=pt,
-                page_bytes=model.kv_page_bytes(pt),
-                model_key=model.cfg.name, metrics=m)
-            # device pools carry one trailing scratch page that inactive
-            # batch rows write into
-            self._kvpages = model.init_kv_pages(                # guarded-by: _cv
-                self.n_pages + 1, pt)
-            self._cache = model.init_cache_paged(               # guarded-by: _cv
-                self.n_slots, self.cache_len)
-            self._tables = np.full((self.n_slots, self.np_max),  # guarded-by: _cv
-                                   self.kvpool.scratch_id, np.int32)
-            # prefix reuse needs every sequence state paged; a model with
-            # any slot-resident kind still pages admission accounting but
-            # keeps the slotted length ceiling (ring/SSM semantics)
-            self._prefix_ok = model.supports_prefix_cache
-            self._all_paged = bool(model.paged_kinds()) and all(
-                k in model.paged_kinds()
-                for k in set(model.pattern) | set(model.tail_kinds))
-        else:
-            self._cache = model.init_cache(self.n_slots, self.cache_len)  # guarded-by: _cv
+        self.n_pages = paged_page_count(
+            model, page_tokens=pt, budget_bytes=kv_budget_bytes,
+            n_slots=self.n_slots, cache_len=self.cache_len)
+        # page-table width == per-request page ceiling; it bounds the
+        # logical attention extent NP*pt, and with it fallback-mode
+        # gather traffic
+        self.np_max = _page_ceiling(self.n_pages, self.cache_len, pt)
+        self.kvpool = kvpages.KVPagePool(
+            n_pages=self.n_pages, page_tokens=pt,
+            page_bytes=model.kv_page_bytes(pt),
+            model_key=model.cfg.name, metrics=m)
+        # device pools carry one trailing scratch page that inactive
+        # batch rows write into
+        self._kvpages = model.init_kv_pages(                    # guarded-by: _cv
+            self.n_pages + 1, pt)
+        self._cache = model.init_cache_paged(                   # guarded-by: _cv
+            self.n_slots, self.cache_len)
+        self._tables = np.full((self.n_slots, self.np_max),      # guarded-by: _cv
+                               self.kvpool.scratch_id, np.int32)
+        # prefix reuse needs every sequence state paged (an unshared
+        # ring/SSM state would diverge)
+        self._prefix_ok = model.supports_prefix_cache
         # host-side per-slot step inputs
         self._tok = np.zeros((self.n_slots, 1), np.int32)    # guarded-by: _cv
         self._pos = np.zeros((self.n_slots,), np.int32)      # guarded-by: _cv
@@ -404,13 +407,11 @@ class DecodeScheduler:
         # (never a bound method: those share jax's global cache by
         # (__func__, __self__) equality — R5)
         self._prefill = _prefill_fn(model, self._fingerprint)
-        self._step = _step_fn(model, self._fingerprint)
         self._join_cache = _join_fn(model, self._fingerprint)
-        if self.paged:
-            self._pstep = _paged_step_fn(model, self._fingerprint)
-            self._prefill_cont = _prefill_cont_fn(model, self._fingerprint)
-            self._gather = _gather_fn(model, self._fingerprint)
-            self._pack = _pack_fn(model, self._fingerprint)
+        self._step = _step_fn(model, self._fingerprint)
+        self._prefill_cont = _prefill_cont_fn(model, self._fingerprint)
+        self._gather = _gather_fn(model, self._fingerprint)
+        self._pack = _pack_fn(model, self._fingerprint)
         # counters
         self.steps = 0
         self.max_occupancy = 0
@@ -429,57 +430,25 @@ class DecodeScheduler:
                  t_first: Optional[float] = None) -> GenResult:
         """Serve one generation request; blocks until it completes.
 
+        Admission reserves whole pages (prefix hits first, the rest
+        all-or-nothing from the pool — blocking backpressure), prefills
+        only the unshared suffix, then joins the batch.
+
         ``first_token``/``t_first`` inject a token already produced
         elsewhere — the cold-start path, where the loading pipeline's
         own in-flight forward answers the prompt (TTFT ~ the pipeline's
         E-completion): the prompt is still prefilled here to build the
-        slot's KV cache, but its logits are discarded and generation
+        request's KV, but its logits are discarded and generation
         resumes at position S+1.
         """
         prompt = _as_prompt(spec.prompt)
         n_prompt = int(prompt.shape[1])
-        if self.paged:
-            return self._generate_paged(spec, prompt, n_prompt,
-                                        first_token, t_first)
-        n_new = validate_spec(spec, n_prompt, self.cache_len)
-
-        with _span("decode.first_token"), _span("decode.prefill"):
-            cache1 = self.model.init_cache(1, self.cache_len)
-            logits, cache1 = self._prefill(self.params, {"tokens": prompt},
-                                           cache1)
-            if first_token is None:
-                jax.block_until_ready(logits)
-                first_token = sample_first(logits, spec, n_prompt)
-                t_first = time.monotonic()
-
-        req = _Active(spec, cache1, int(first_token), float(t_first),
-                      n_prompt, n_new)
-        if req.remaining == 0 or (spec.eos_id is not None
-                                  and req.tokens[-1] == spec.eos_id):
-            return GenResult(req.tokens, req.times, n_prompt)
-
-        with self._cv:
-            self._pending.append(req)
-            self._cv.notify_all()
-        self._pump(req)
-        if req.error is not None:
-            raise req.error
-        return GenResult(req.tokens, req.times, n_prompt)
-
-    def _generate_paged(self, spec: GenerateSpec, prompt, n_prompt: int,
-                        first_token, t_first) -> GenResult:
-        """Paged admission: reserve whole pages (prefix hits first, the
-        rest all-or-nothing from the pool — blocking backpressure, never
-        a per-slot length ceiling), prefill only the unshared suffix,
-        then join the batch like any slotted request."""
         pt = self.page_tokens
-        n_new = validate_spec_paged(spec, n_prompt, page_tokens=pt,
-                                    n_pages=self.np_max,
-                                    stats=self.kvpool.stats())
-        if not self._all_paged:
-            # some sequence state is still slot-resident (ring / SSM):
-            # its capacity ceiling applies unchanged
-            n_new = validate_spec(spec, n_prompt, self.cache_len)
+        n_new = validate_spec(self.model, spec, n_prompt,
+                              n_slots=self.n_slots, cache_len=self.cache_len,
+                              page_tokens=pt,
+                              budget_bytes=self.kv_budget_bytes,
+                              stats=self.kvpool.stats())
         need = -(-(n_prompt + n_new) // pt)
         with _span("decode.first_token"):
             hit: List[int] = []
@@ -563,13 +532,12 @@ class DecodeScheduler:
                    "max_occupancy": self.max_occupancy,
                    "active": len(self._slots) + len(self._pending),
                    "n_slots": self.n_slots}
-        if self.paged:
-            ps = self.kvpool.stats()
-            out.update(kv_page_tokens=self.page_tokens,
-                       kv_pages_total=ps.total, kv_pages_used=ps.used,
-                       kv_pages_pinned=ps.pinned,
-                       kv_prefix_hits=ps.prefix_hits,
-                       kv_prefix_misses=ps.prefix_misses)
+        ps = self.kvpool.stats()
+        out.update(kv_page_tokens=self.page_tokens,
+                   kv_pages_total=ps.total, kv_pages_used=ps.used,
+                   kv_pages_pinned=ps.pinned,
+                   kv_prefix_hits=ps.prefix_hits,
+                   kv_prefix_misses=ps.prefix_misses)
         return out
 
     def reset_peaks(self):
@@ -587,11 +555,7 @@ class DecodeScheduler:
             req = self._pending.popleft()
             slot = min(self._free)
             self._free.remove(slot)
-            if self.paged:
-                self._join_paged_locked(req, slot)
-            else:
-                self._cache = self._join_cache(self._cache, req.cache1,
-                                               jnp.int32(slot))
+            self._join_locked(req, slot)
             req.cache1 = None
             self._slots[slot] = req
             self._tok[slot, 0] = req.tokens[-1]
@@ -603,8 +567,8 @@ class DecodeScheduler:
             self._m_joined.inc()
             self._m_occ.set(len(self._slots))
 
-    def _join_paged_locked(self, req: _Active, slot: int):
-        """Paged half of admission (caller holds the lock): merge the
+    def _join_locked(self, req: _Active, slot: int):
+        """Device half of admission (caller holds the lock): merge the
         slot-resident state, move new prompt pages from the request's
         contiguous prefill cache into the pool, publish their hashes for
         prefix reuse, and point the slot's page-table row at them."""
@@ -633,7 +597,7 @@ class DecodeScheduler:
         self._tables[slot, :] = self.kvpool.scratch_id
         self._tables[slot, :len(ids)] = ids
 
-    def _leave_paged_locked(self, req: _Active, slot: int):
+    def _leave_locked(self, req: _Active, slot: int):
         """Release a leaver's page references and park its table row on
         the scratch page at position 0, so the dead row costs the
         kernel one page (caller holds the lock)."""
@@ -648,12 +612,11 @@ class DecodeScheduler:
         self._stepping = False
         for req in list(self._slots.values()) + list(self._pending):
             req.error = e
-            if self.paged and req.page_ids:
+            if req.page_ids:
                 self.kvpool.release(req.page_ids)
                 req.page_ids = []
-        if self.paged:
-            self._tables[:, :] = self.kvpool.scratch_id
-            self._pos[:] = 0
+        self._tables[:, :] = self.kvpool.scratch_id
+        self._pos[:] = 0
         self._slots.clear()
         self._pending.clear()
         self._free = list(range(self.n_slots))
@@ -679,22 +642,16 @@ class DecodeScheduler:
                     try:
                         with _span("decode.admit"):
                             self._admit_locked()
-                        params, cache = self.params, self._cache
-                        tok = jnp.asarray(self._tok)
-                        pos = jnp.asarray(self._pos)
-                        seed = jnp.asarray(self._seed)
-                        temp = jnp.asarray(self._temp)
-                        if self.paged:
-                            pages = int((self._pos // self.page_tokens
-                                         + 1).sum())
-                            # dispatched under the lock: the step
-                            # consumes the pools, and a prefix hit's
-                            # gather must read the step's output, never
-                            # the donated input
-                            nxt, new_cache, self._kvpages = self._pstep(
-                                params, cache, self._kvpages,
-                                jnp.asarray(self._tables), tok, pos, seed,
-                                temp)
+                        pages = int((self._pos // self.page_tokens
+                                     + 1).sum())
+                        # dispatched under the lock: the step consumes
+                        # the pools, and a prefix hit's gather must read
+                        # the step's output, never the donated input
+                        nxt, new_cache, self._kvpages = self._step(
+                            self.params, self._cache, self._kvpages,
+                            jnp.asarray(self._tables),
+                            jnp.asarray(self._tok), jnp.asarray(self._pos),
+                            jnp.asarray(self._seed), jnp.asarray(self._temp))
                     except BaseException as e:
                         # anything failing while _stepping is set must
                         # fail ALL residents, or their threads wait
@@ -702,9 +659,6 @@ class DecodeScheduler:
                         self._fail_locked(e)
                         raise
                 try:
-                    if not self.paged:
-                        nxt, new_cache = self._step(params, cache, tok,
-                                                    pos, seed, temp)
                     nxt_host = np.asarray(nxt)
                 except BaseException as e:
                     with self._cv:
@@ -728,11 +682,9 @@ class DecodeScheduler:
                             req.done = True
                             del self._slots[slot]
                             self._free.append(slot)
-                            if self.paged:
-                                self._leave_paged_locked(req, slot)
+                            self._leave_locked(req, slot)
                     self._m_steps.inc()
-                    if self.paged:
-                        self._m_pages.inc(pages)
+                    self._m_pages.inc(pages)
                     self._m_occ.set(len(self._slots))
                     self._stepping = False
                     self._cv.notify_all()
@@ -768,7 +720,8 @@ def reference_generate(model, params: PyTree, prompt, *, n_new: int,
                         max_len=max_len, eos_id=eos_id, seed=seed)
     prompt = _as_prompt(prompt)
     S = int(prompt.shape[1])
-    n_new = validate_spec(spec, S, cache_len)
+    n_new = _clamp_n_new(spec, S)
+    _check_cache_len(S, n_new, cache_len)
 
     prefill, dec = _ref_fns(model, ops.registry.fingerprint())
     cache = model.init_cache(1, cache_len)

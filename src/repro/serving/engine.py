@@ -1,5 +1,5 @@
 """Serverless serving engine: trace replay on the Router/InstancePool
-platform API, plus the compat LM-server shim.
+platform API.
 
 :class:`ServerlessPlatform` wires one :class:`InstancePool` per deployed
 model behind a :class:`Router` and replays invocation traces through it.
@@ -15,18 +15,14 @@ and the eviction policy (default: the seed's TTL rule) reclaims idle
 instances — re-triggering cold starts, the serverless lifecycle of the
 paper's Fig. 2.
 
-The classes the old API exposed (``FunctionInstance``, ``Response``,
-``BatchedLMServer``) are re-exported / shimmed here so existing
-benchmarks and examples run unmodified.
+The classes the old API exposed (``FunctionInstance``, ``Response``)
+are re-exported here so existing benchmarks and examples run
+unmodified.
 """
 from __future__ import annotations
 
 import time
-from concurrent.futures import ThreadPoolExecutor
 from typing import Any, Callable, Dict, List, Optional
-
-import jax
-import jax.numpy as jnp
 
 from repro import metrics as metrics_mod
 from repro.serving.api import GenerateSpec, Request, Response  # noqa: F401
@@ -37,8 +33,6 @@ from repro.serving.pool import FunctionInstance, InstancePool  # noqa: F401
 from repro.serving.router import Router
 from repro.store.cache import CacheStats, WeightCache
 from repro.store.store import WeightStore
-
-PyTree = Any
 
 
 class ServerlessPlatform:
@@ -53,7 +47,7 @@ class ServerlessPlatform:
                  cache_budget_bytes: Optional[int] = None,
                  cache: Optional[WeightCache] = None,
                  gen_slots: int = 8, gen_cache_len: int = 256,
-                 kv_page_tokens: Optional[int] = None,
+                 kv_page_tokens: int = 16,
                  kv_budget_bytes: Optional[int] = None,
                  mesh_shape=None, rules=None, compute_quant: bool = False,
                  metrics: Optional[metrics_mod.MetricsRegistry] = None,
@@ -69,17 +63,17 @@ class ServerlessPlatform:
         cache across several platforms on a node).
 
         gen_slots / gen_cache_len: per-instance continuous-batching
-        capacity — up to gen_slots concurrent generation requests share
-        one slotted KV cache of gen_cache_len positions per slot.
+        capacity — up to gen_slots concurrent generation requests of at
+        most gen_cache_len positions each.
 
-        kv_page_tokens / kv_budget_bytes: block-paged decode KV — every
-        instance's scheduler serves full-attention KV from a shared
-        refcounted page pool (kv_page_tokens positions per page, pool
-        sized by kv_budget_bytes; None -> slotted-arena-equivalent page
-        count).  Mixed prompt lengths admit against the page budget
-        instead of a per-slot ceiling, and requests sharing a prompt
-        prefix pin the same physical pages (prefill skips the shared
-        span).  ``kv.*`` gauges/counters land in metrics_snapshot().
+        kv_page_tokens / kv_budget_bytes: every instance's scheduler
+        serves full-attention KV from a shared refcounted page pool
+        (kv_page_tokens positions per page, pool sized by
+        kv_budget_bytes; None -> gen_slots x gen_cache_len positions'
+        worth).  Mixed prompt lengths admit against the page budget, and
+        requests sharing a prompt prefix pin the same physical pages
+        (prefill skips the shared span).  ``kv.*`` gauges/counters land
+        in metrics_snapshot().
 
         mesh_shape / rules: shard-granular cold starts — every
         instance's pipeline streams weights onto a ``(data, model)``
@@ -235,52 +229,3 @@ class ServerlessPlatform:
         finally:
             router.shutdown()
             self.last_router_stats = router.stats
-
-
-# ---------------------------------------------------------------------------
-# LM batched serving — compat shim over the DecodeScheduler
-# ---------------------------------------------------------------------------
-
-class BatchedLMServer:
-    """Compat shim: the old static-batch server surface, served by the
-    slot-based continuous-batching :class:`DecodeScheduler`.
-
-    Differences from the old implementation (both deliberate fixes):
-    ``max_batch`` is *honored* as the scheduler's slot count (it was a
-    dead knob), and a prompt+n_new that overflows ``cache_len`` raises
-    :class:`~repro.serving.api.CacheOverflowError` instead of silently
-    wrapping/dropping KV entries past the cache end."""
-
-    def __init__(self, model, params: PyTree, *, max_batch: int = 8,
-                 cache_len: int = 256):
-        self.model = model
-        self.params = params
-        self.max_batch = int(max_batch)
-        self.cache_len = int(cache_len)
-        self.scheduler = DecodeScheduler(model, params, n_slots=max_batch,
-                                         cache_len=cache_len)
-
-    def generate(self, tokens: jax.Array, *, n_new: int,
-                 greedy: bool = True, temperature: float = 1.0,
-                 seed: int = 0) -> jax.Array:
-        """tokens: (B, S) prompt batch -> (B, n_new) generated ids.
-
-        Rows are submitted as B concurrent generation requests, so they
-        decode as one continuous batch through the shared slotted KV
-        cache (the old server stepped a private static batch)."""
-        B, S = tokens.shape
-        if B > self.max_batch:
-            raise ValueError(
-                f"batch {B} exceeds max_batch={self.max_batch} "
-                f"(the scheduler's slot count)")
-        specs = [GenerateSpec(prompt=tokens[b], n_new=n_new,
-                              temperature=0.0 if greedy else temperature,
-                              seed=seed + b)
-                 for b in range(B)]
-        if B == 1:
-            rows = [self.scheduler.generate(specs[0]).tokens]
-        else:
-            with ThreadPoolExecutor(max_workers=B) as ex:
-                rows = list(ex.map(
-                    lambda s: self.scheduler.generate(s).tokens, specs))
-        return jnp.asarray(rows, jnp.int32)

@@ -1,13 +1,12 @@
 """Block-paged KV memory: the :class:`KVPagePool` page allocator.
 
-The slotted decode arena (``init_cache(n_slots, cache_len)``) sized
-every slot for the longest request; paged KV splits decode memory into
-fixed-size **pages** of ``page_tokens`` positions and gives each
-resident request a *page table* instead of a contiguous slot.  This
-module is the host-side bookkeeping half — the device arrays (one
-``(n_pages, K, page_tokens, dh)`` pool per attention layer) are owned
-by the :class:`~repro.serving.decode.DecodeScheduler`, which consults
-this pool for every allocate / share / free decision.
+Decode KV memory is split into fixed-size **pages** of ``page_tokens``
+positions; each resident request holds a *page table* instead of a
+contiguous row sized for the longest request.  This module is the
+host-side bookkeeping half — the device arrays (one lane-dense page
+pool per paged layer group, ``init_kv_pages``) are owned by the
+:class:`~repro.serving.decode.DecodeScheduler`, which consults this
+pool for every allocate / share / free decision.
 
 Disciplines (the serving-side twin of the WeightCache, now for KV):
 

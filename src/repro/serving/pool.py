@@ -6,8 +6,8 @@ and goes through the Cicada pipeline (``ColdStartEngine``) — the
 triggering request's inference is computed layer-by-layer *inside* the
 loading pipeline.  Subsequent requests are **warm**: direct steady-state
 forward, or — for generation requests — a join into the instance's
-:class:`~repro.serving.decode.DecodeScheduler`, the slot-based
-continuous-batching decode engine each live instance owns.
+:class:`~repro.serving.decode.DecodeScheduler`, the continuous-batching
+decode engine over KV pages each live instance owns.
 
 :class:`InstancePool` owns up to ``max_instances`` containers for one
 model function and hands them out under two disciplines:
@@ -42,8 +42,7 @@ from repro import analysis, metrics as metrics_mod
 from repro.core.coldstart import ColdStartEngine, LoadResult
 from repro.serving.api import GenerateSpec, PoolStats
 from repro.serving.decode import (DecodeScheduler, GenResult, sample_first,
-                                  paged_page_count, validate_spec,
-                                  validate_spec_paged, _as_prompt)
+                                  validate_spec, _as_prompt)
 from repro.serving.policy import EvictionPolicy, NeverEvict
 from repro.store.cache import WeightCache
 from repro.store.store import WeightStore
@@ -63,15 +62,17 @@ class FunctionInstance:
                  example_batch: Optional[Dict[str, jax.Array]] = None,
                  cache: Optional[WeightCache] = None,
                  gen_slots: int = 8, gen_cache_len: int = 256,
-                 kv_page_tokens: Optional[int] = None,
+                 kv_page_tokens: int = 16,
                  kv_budget_bytes: Optional[int] = None,
                  mesh_shape=None, rules=None, compute_quant: bool = False,
                  metrics: Optional[metrics_mod.MetricsRegistry] = None,
                  source=None):
-        """gen_slots / gen_cache_len: capacity of this container's
-        continuous-batching DecodeScheduler — concurrent generation
-        requests up to gen_slots share one slotted KV cache of
-        gen_cache_len positions per slot.
+        """gen_slots / gen_cache_len / kv_page_tokens / kv_budget_bytes:
+        shape of this container's continuous-batching DecodeScheduler —
+        up to gen_slots concurrent generation requests of at most
+        gen_cache_len positions each, their KV in one pool of
+        kv_page_tokens-position pages sized by kv_budget_bytes (None:
+        gen_slots x gen_cache_len positions' worth).
 
         mesh_shape / rules: shard-granular cold starts — weights stream
         onto a ``(data, model)`` device mesh of this shape (e.g.
@@ -105,8 +106,6 @@ class FunctionInstance:
         self.last_load: Optional[LoadResult] = None
         self.gen_slots = int(gen_slots)
         self.gen_cache_len = int(gen_cache_len)
-        # kv_page_tokens != None switches the scheduler to block-paged
-        # KV (kv_budget_bytes caps the pool; None -> slotted-equivalent)
         self.kv_page_tokens = kv_page_tokens
         self.kv_budget_bytes = kv_budget_bytes
         self.scheduler: Optional[DecodeScheduler] = None
@@ -143,7 +142,7 @@ class FunctionInstance:
 
     def evict(self):
         self.params = None
-        self.scheduler = None          # slotted KV cache dies with the params
+        self.scheduler = None          # its KV pages die with the params
         if self.last_load is not None:
             # the load's trace stays readable; its params and first
             # logits would pin the evicted weights on the device
@@ -199,23 +198,15 @@ class FunctionInstance:
         """
         prompt = _as_prompt(spec.prompt)
         n_prompt = int(prompt.shape[1])
-        # fail before the expensive load, not after
-        if self.kv_page_tokens:
-            n_pages = paged_page_count(
-                self.model, page_tokens=self.kv_page_tokens,
-                budget_bytes=self.kv_budget_bytes,
-                n_slots=self.gen_slots, cache_len=self.gen_cache_len)
-            # per-request ceiling mirrors DecodeScheduler's np_max
-            # default (page-table width = ceil(cache_len / pt))
-            np_max = max(1, min(
-                n_pages, -(-self.gen_cache_len // self.kv_page_tokens)))
-            sched = self.scheduler
-            validate_spec_paged(
-                spec, n_prompt, page_tokens=self.kv_page_tokens,
-                n_pages=np_max,
-                stats=sched.kvpool.stats() if sched is not None else None)
-        else:
-            validate_spec(spec, n_prompt, self.gen_cache_len)
+        # fail before the expensive load, not after: the scheduler's
+        # own admission rule
+        sched = self.scheduler
+        validate_spec(self.model, spec, n_prompt, n_slots=self.gen_slots,
+                      cache_len=self.gen_cache_len,
+                      page_tokens=self.kv_page_tokens,
+                      budget_bytes=self.kv_budget_bytes,
+                      stats=sched.kvpool.stats() if sched is not None
+                      else None)
         if not self.live:
             first: Dict[str, Any] = {}
 
@@ -268,7 +259,7 @@ class InstancePool:
                  instance_factory: Optional[Callable[[], Any]] = None,
                  cache: Optional[WeightCache] = None,
                  gen_slots: int = 8, gen_cache_len: int = 256,
-                 kv_page_tokens: Optional[int] = None,
+                 kv_page_tokens: int = 16,
                  kv_budget_bytes: Optional[int] = None,
                  mesh_shape=None, rules=None, compute_quant: bool = False,
                  metrics: Optional[metrics_mod.MetricsRegistry] = None,
@@ -281,8 +272,9 @@ class InstancePool:
         cold starts then single-flight each (unit, shard) store read.
         ``source``: ShardSource for cache-missing retrieval streams
         (the cluster peer-exchange tier; default: origin store).
-        ``gen_slots``/``gen_cache_len``: per-instance DecodeScheduler
-        capacity (concurrent generation residency / KV positions).
+        ``gen_slots``/``gen_cache_len``/``kv_page_tokens``/
+        ``kv_budget_bytes``: per-instance DecodeScheduler shape (see
+        FunctionInstance).
         ``mesh_shape``/``rules``: shard-granular cold starts (see
         FunctionInstance)."""
         self.model_name = model_name

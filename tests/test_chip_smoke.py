@@ -70,7 +70,7 @@ def test_smoke_fails_outside_the_repo(tmp_path):
 def test_single_chip_phases_at_smoke_size(cs, monkeypatch, tmp_path):
     """Every check of the one-chip run passes on a smoke-size model:
     cold logits vs the reference, first token inside the load, warm
-    joins, prefix hits, token identity in slotted and paged mode, and
+    joins, prefix hits, token identity over KV pages, and
     every main-path kernel dispatched through its Pallas body.  On the
     CPU every token stream is identical to ``reference_generate``."""
     monkeypatch.setenv("REPRO_PALLAS", "interpret")

@@ -18,10 +18,9 @@ import pytest
 
 from repro.models import transformer
 from repro.models.api import get_config
-from repro.serving import (BatchedLMServer, CacheOverflowError,
-                           DecodeScheduler, GenerateSpec, InstancePool,
-                           Request, Router, UnknownModelError,
-                           reference_generate)
+from repro.serving import (CacheOverflowError, DecodeScheduler,
+                           GenerateSpec, InstancePool, Request, Router,
+                           UnknownModelError, reference_generate)
 from repro.store.store import WeightStore, deploy_model
 
 CACHE_LEN = 64
@@ -187,7 +186,7 @@ def test_sampled_generation_deterministic_and_matches_reference(dense):
 
 
 # ---------------------------------------------------------------------------
-# KV-cache overflow validation + honored max_batch (old silent bugs)
+# KV-cache overflow validation (an old silent bug)
 # ---------------------------------------------------------------------------
 
 def test_overflow_raises_instead_of_silent_wrap(dense):
@@ -211,18 +210,6 @@ def test_max_len_clamps_n_new(dense):
     with pytest.raises(CacheOverflowError):    # no room to generate at all
         sched.generate(GenerateSpec(prompt=p, n_new=4,
                                     max_len=PROMPT_LEN))
-
-
-def test_batched_server_honors_max_batch(dense):
-    cfg, m, params = dense
-    srv = BatchedLMServer(m, params, max_batch=2, cache_len=CACHE_LEN)
-    toks = jnp.asarray(np.stack([_prompt(cfg, i) for i in range(3)]))
-    with pytest.raises(ValueError, match="max_batch"):
-        srv.generate(toks, n_new=4)            # was a dead knob before
-    out = srv.generate(toks[:2], n_new=4)
-    assert out.shape == (2, 4)
-    with pytest.raises(CacheOverflowError):
-        srv.generate(toks[:1], n_new=CACHE_LEN)
 
 
 # ---------------------------------------------------------------------------
@@ -355,9 +342,10 @@ def test_unknown_model_typed_error_on_submitting_thread():
 
 def test_scheduler_runs_interpret_kernels_bit_identical(dense, monkeypatch):
     """The DecodeScheduler's jitted prefill/step dispatch the *Pallas
-    kernel bodies* — the registry records the dispatches — and the
-    token stream stays bit-identical to the serial reference traced
-    under the same mode.  Default (and any non-TPU run): interpret
+    kernel bodies* (flash prefill, paged decode attention, the in-place
+    page write) — the registry records the dispatches — and the token
+    stream stays bit-identical to the serial reference, whose
+    contiguous-cache decode kernel is traced under the same mode.  Default (and any non-TPU run): interpret
     mode; with REPRO_PALLAS=pallas on a TPU the same assertions hold
     against the real Mosaic lowerings."""
     import os
@@ -371,17 +359,22 @@ def test_scheduler_runs_interpret_kernels_bit_identical(dense, monkeypatch):
     monkeypatch.setenv("REPRO_PALLAS", mode)
     before = ops.registry.dispatch_snapshot()
     sched = DecodeScheduler(m, params, n_slots=2, cache_len=CACHE_LEN)
-    assert sched.kernel_modes["flash_attention"] == mode
-    assert sched.kernel_modes["decode_attention"] == mode
+    served = ("flash_attention", "decode_attention_paged",
+              "kv_pages_write")
+    for kern in served + ("decode_attention",):
+        assert sched.kernel_modes[kern] == mode, kern
     spec = GenerateSpec(prompt=_prompt(cfg, 5), n_new=4)
     got = sched.generate(spec).tokens
+    after = ops.registry.dispatch_snapshot()
+    for kern in served:
+        assert after.get((kern, mode), 0) > \
+            before.get((kern, mode), 0), kern
     want = reference_generate(m, params, spec.prompt, n_new=4,
                               cache_len=CACHE_LEN)
     assert got == want
-    after = ops.registry.dispatch_snapshot()
-    for kern in ("flash_attention", "decode_attention"):
-        assert after.get((kern, mode), 0) > \
-            before.get((kern, mode), 0), kern
+    ref = ops.registry.dispatch_snapshot()
+    assert ref.get(("decode_attention", mode), 0) > \
+        after.get(("decode_attention", mode), 0)
 
 
 def test_registry_auto_probes_and_forces(monkeypatch):

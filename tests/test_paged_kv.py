@@ -1,9 +1,10 @@
 """Paged KV cache: KVPagePool allocator semantics (refcounts, prefix
 index, cached LRU, copy-on-write, blocking backpressure), paged
 DecodeScheduler token-equivalence against ``reference_generate``,
-page-budget admission of mixed prompt lengths that overflow the slotted
-arena, and physical page sharing across requests with a common prompt
-prefix.
+page-budget admission of mixed prompt lengths beyond a per-request
+share of the pool, the one admission rule shared by the scheduler and
+the instance that owns it, and physical page sharing across requests
+with a common prompt prefix.
 
 The pool storm test exercises the allocator from many threads with
 ``check_invariants`` between operations — CI runs this file under
@@ -23,7 +24,7 @@ from repro.models import transformer
 from repro.models.api import get_config
 from repro.serving import (CacheOverflowError, DecodeScheduler,
                            GenerateSpec, reference_generate)
-from repro.serving.decode import paged_page_count, validate_spec_paged
+from repro.serving.decode import paged_page_count, validate_spec
 from repro.serving.kvpages import KVPagePool, page_hashes
 
 CACHE_LEN = 64
@@ -184,34 +185,77 @@ def test_pool_storm_invariants():
 
 
 # ---------------------------------------------------------------------------
-# validate_spec_paged
+# validate_spec: the one admission rule
 # ---------------------------------------------------------------------------
 
-def test_validate_spec_paged_message_and_typing():
+def test_validate_spec_paged_message_and_typing(dense):
+    """A request beyond the per-request page ceiling is a typed error
+    that names the ceiling (here 4 pages: the pool's 8, capped at
+    cache_len's worth) and, given the live pool's stats, its
+    occupancy; a fitting request never raises, whatever the
+    occupancy."""
+    _, m, _ = dense
+    shape = dict(n_slots=2, cache_len=32, page_tokens=8)
     spec = GenerateSpec(prompt=[1, 2, 3], n_new=100)
     with pytest.raises(CacheOverflowError) as ei:
-        validate_spec_paged(spec, 3, page_tokens=8, n_pages=4)
+        validate_spec(m, spec, 3, **shape)
     msg = str(ei.value)
     assert "4 pages x 8 tokens" in msg and "32 tokens" in msg
     pool = KVPagePool(n_pages=4, page_tokens=8)
     held = pool.alloc(3)
     with pytest.raises(CacheOverflowError) as ei:
-        validate_spec_paged(spec, 3, page_tokens=8, n_pages=4,
-                            stats=pool.stats())
+        validate_spec(m, spec, 3, stats=pool.stats(), **shape)
     assert "live occupancy 3/4 pages" in str(ei.value)
     pool.release(held)
     # fitting requests never raise here, whatever the live occupancy
-    assert validate_spec_paged(GenerateSpec(prompt=[1], n_new=8), 1,
-                               page_tokens=8, n_pages=4) == 8
+    assert validate_spec(m, GenerateSpec(prompt=[1], n_new=8), 1,
+                         **shape) == 8
+
+
+def test_instance_refuses_what_its_scheduler_would_before_the_load(
+        tmp_path):
+    """A model that keeps state per slot (recurrentgemma's ring window
+    and RG-LRU) is capped at cache_len positions, below its page
+    ceiling when cache_len is not a multiple of the page size.  The
+    instance applies the scheduler's own rule, so a request the
+    scheduler refuses fails before the cold load is paid."""
+    from repro.serving.pool import FunctionInstance
+    from repro.store.store import WeightStore
+    cfg = _f32_cfg("recurrentgemma-2b")
+    m = transformer.build(cfg)
+    assert not m.supports_prefix_cache
+    cache_len = 40                          # 2.5 pages of PT: ceiling 3
+    spec = GenerateSpec(prompt=_prompt(cfg, 1), n_new=36)   # 44 tokens
+    sched = DecodeScheduler(m, m.init(jax.random.key(0)), n_slots=2,
+                            cache_len=cache_len, kv_page_tokens=PT)
+    with pytest.raises(CacheOverflowError, match="cache_len=40"):
+        sched.generate(spec)
+    inst = FunctionInstance(m, "rg", WeightStore(str(tmp_path)), warm=False,
+                            gen_slots=2, gen_cache_len=cache_len,
+                            kv_page_tokens=PT)
+    loads = []
+
+    def load(*args, **kw):
+        loads.append(args)
+        raise AssertionError("the cold load ran")
+
+    inst.engine.load = load
+    with pytest.raises(CacheOverflowError, match="cache_len=40"):
+        inst.generate(spec)
+    assert loads == [] and not inst.live
 
 
 def test_paged_page_count_budget(dense):
-    _, m, _ = dense
+    _, m, params = dense
+    # every scheduler pages: no page size is no scheduler
+    for pt in (None, 0):
+        with pytest.raises(ValueError, match="kv_page_tokens"):
+            DecodeScheduler(m, params, kv_page_tokens=pt)
     per = m.kv_page_bytes(PT)
     assert per > 0
     assert paged_page_count(m, page_tokens=PT,
                             budget_bytes=10 * per + 3) == 10
-    # no byte budget -> the slotted arena's worth of pages
+    # no byte budget -> every slot's cache_len positions' worth
     assert paged_page_count(m, page_tokens=PT, n_slots=4,
                             cache_len=CACHE_LEN) == 4 * (CACHE_LEN // PT)
     with pytest.raises(ValueError):
@@ -232,7 +276,7 @@ def test_paged_scheduler_bit_identical(arch):
     m = transformer.build(cfg)
     params = m.init(jax.random.key(0))
     sched = DecodeScheduler(m, params, n_slots=3, cache_len=CACHE_LEN,
-                            kv_page_tokens=PT, kv_max_seq=CACHE_LEN)
+                            kv_page_tokens=PT)
     lens = (5, 8, 19)
     prompts = [_prompt(cfg, i, n=lens[i]) for i in range(3)]
     results = [None] * 3
@@ -258,39 +302,58 @@ def test_paged_scheduler_bit_identical(arch):
 
 
 def test_paged_admits_mixed_lengths_beyond_slotted_ceiling(dense):
-    """N requests whose prompts overflow the slotted per-slot arena all
-    admit and complete under the *same* byte budget paged."""
+    """Two mixed-length requests share an 8-page pool: the long one
+    holds more than a per-request share (8 pages / 2 slots), the pages
+    the two need together exceed the pool, so one waits for the other's
+    pages; both complete and match their references."""
     cfg, m, params = dense
-    n_slots, cache_len = 2, 32
-    slotted = DecodeScheduler(m, params, n_slots=n_slots,
-                              cache_len=cache_len)
-    long_prompt = _prompt(cfg, 42, n=40)   # 40 + 8 > 32: slotted rejects
-    with pytest.raises(CacheOverflowError):
-        slotted.generate(GenerateSpec(prompt=long_prompt, n_new=8))
-    # same budget, paged: n_slots * cache_len = 64 tokens = 8 x 8-token
-    # pages shared across residents instead of 32 per slot
+    n_slots, cache_len = 2, 64
+    # 64 tokens = 8 x 8-token pages for both residents, not 32 per slot
     paged = DecodeScheduler(m, params, n_slots=n_slots,
                             cache_len=cache_len, kv_page_tokens=8,
-                            kv_max_seq=2 * cache_len,
-                            kv_budget_bytes=n_slots * cache_len // 8
+                            kv_budget_bytes=n_slots * 32 // 8
                             * m.kv_page_bytes(8))
-    assert paged.n_pages == 8
-    prompts = [long_prompt, _prompt(cfg, 43, n=9)]
+    assert paged.n_pages == 8 and paged.np_max == 8
+    # 40 + 8 tokens: 6 pages, beyond a 4-page share; 9 + 8 tokens: 3
+    prompts = [_prompt(cfg, 42, n=40), _prompt(cfg, 43, n=9)]
     results = [None] * 2
+    # the long request's first step waits until the short one has asked
+    # for its pages, so the short one asks while the long one holds 6
+    long_steps, short_asked, free_when_asked = (
+        threading.Event(), threading.Event(), [])
+    step, alloc = paged._step, paged.kvpool.alloc
+
+    def spy_step(*args):
+        long_steps.set()
+        assert short_asked.wait(timeout=60)
+        return step(*args)
+
+    def spy_alloc(n, **kw):
+        if threading.current_thread() is threading.main_thread():
+            st = paged.kvpool.stats()
+            free_when_asked.append((n, st.total - st.pinned))
+            short_asked.set()
+        return alloc(n, **kw)
+
+    paged._step, paged.kvpool.alloc = spy_step, spy_alloc
 
     def run(i):
         results[i] = paged.generate(
             GenerateSpec(prompt=prompts[i], n_new=8, seed=i))
 
-    threads = [threading.Thread(target=run, args=(i,)) for i in range(2)]
-    for t in threads:
-        t.start()
-    for t in threads:
-        t.join()
+    t = threading.Thread(target=run, args=(0,))
+    t.start()
+    assert long_steps.wait(timeout=120)
+    run(1)
+    t.join(timeout=120)
+    assert not t.is_alive()
+    assert free_when_asked == [(3, 2)]     # 3 pages wanted, 2 to be had
+    assert results[1].t_first >= results[0].token_times[-1]
     for i in range(2):
         ref = reference_generate(m, params, prompts[i], n_new=8,
                                  cache_len=64, seed=i)
         assert results[i].tokens == ref, i
+    assert paged.kvpool.stats().pinned == 0
     paged.kvpool.check_invariants()
 
 
@@ -301,7 +364,7 @@ def test_paged_moe_full_attention_prefix():
     assert m.supports_prefix_cache
     params = m.init(jax.random.key(0))
     sched = DecodeScheduler(m, params, n_slots=2, cache_len=CACHE_LEN,
-                            kv_page_tokens=PT, kv_max_seq=CACHE_LEN)
+                            kv_page_tokens=PT)
     shared = _prompt(cfg, 7, n=2 * PT)
     pa = np.concatenate([shared, _prompt(cfg, 8, n=5)])
     ra = sched.generate(GenerateSpec(prompt=pa, n_new=5, seed=1))
@@ -324,7 +387,7 @@ def test_shared_prefix_pins_same_physical_pages(dense):
     request's tokens are bit-identical to its serial reference."""
     cfg, m, params = dense
     sched = DecodeScheduler(m, params, n_slots=2, cache_len=CACHE_LEN,
-                            kv_page_tokens=PT, kv_max_seq=CACHE_LEN)
+                            kv_page_tokens=PT)
     shared = _prompt(cfg, 100, n=2 * PT)               # 2 full pages
     pa = np.concatenate([shared, _prompt(cfg, 101, n=6)])
     ra = sched.generate(GenerateSpec(prompt=pa, n_new=6, seed=3))
@@ -350,7 +413,7 @@ def test_identical_prompt_full_hit_still_generates(dense):
     prefill."""
     cfg, m, params = dense
     sched = DecodeScheduler(m, params, n_slots=2, cache_len=CACHE_LEN,
-                            kv_page_tokens=PT, kv_max_seq=CACHE_LEN)
+                            kv_page_tokens=PT)
     p = _prompt(cfg, 55, n=2 * PT)                     # page-aligned
     ref = reference_generate(m, params, p, n_new=5, cache_len=CACHE_LEN,
                              seed=9)
@@ -368,7 +431,7 @@ def test_kv_metrics_wired(dense):
     cfg, m, params = dense
     reg = MetricsRegistry()
     sched = DecodeScheduler(m, params, n_slots=2, cache_len=CACHE_LEN,
-                            kv_page_tokens=PT, kv_max_seq=CACHE_LEN,
+                            kv_page_tokens=PT,
                             metrics=reg)
     sched.generate(GenerateSpec(prompt=_prompt(cfg, 1, n=PT + 1), n_new=4))
     snap = reg.snapshot()
@@ -389,17 +452,17 @@ def test_paged_leave_resets_pos_and_counts_attended_pages(dense):
     cfg, m, params = dense
     reg = MetricsRegistry()
     sched = DecodeScheduler(m, params, n_slots=2, cache_len=CACHE_LEN,
-                            kv_page_tokens=PT, kv_max_seq=CACHE_LEN,
+                            kv_page_tokens=PT,
                             metrics=reg)
     attended = reg.counter("decode/kv_pages_attended")
     seen = []                               # (pos, counter before step)
-    step = sched._pstep
+    step = sched._step
 
     def spy(params, cache, pools, tables, tok, pos, *rest):
         seen.append((np.asarray(pos).copy(), attended.value))
         return step(params, cache, pools, tables, tok, pos, *rest)
 
-    sched._pstep = spy
+    sched._step = spy
     sched.generate(GenerateSpec(prompt=_prompt(cfg, 1, n=2 * PT + 3),
                                 n_new=4))
     assert sched._pos.tolist() == [0, 0]
@@ -425,7 +488,7 @@ def test_pack_and_prefill_donate_their_buffers(dense):
     pack consumes it, and still generates its reference tokens."""
     cfg, m, params = dense
     sched = DecodeScheduler(m, params, n_slots=2, cache_len=CACHE_LEN,
-                            kv_page_tokens=PT, kv_max_seq=CACHE_LEN)
+                            kv_page_tokens=PT)
     seen = {"pack": [], "prefill": []}
     pack, prefill = sched._pack, sched._prefill
 
@@ -486,15 +549,15 @@ def test_step_consumes_the_pools_it_was_given(monkeypatch, mode, arch):
     assert m.tail_kinds == (("attn",) if arch == "attn-tail" else ())
     params = m.init(jax.random.key(0))
     sched = DecodeScheduler(m, params, n_slots=2, cache_len=CACHE_LEN,
-                            kv_page_tokens=PT, kv_max_seq=CACHE_LEN)
+                            kv_page_tokens=PT)
     seen = []
-    step = sched._pstep
+    step = sched._step
 
     def spy(params, cache, pools, *rest):
         seen.append(jax.tree.leaves(pools))
         return step(params, cache, pools, *rest)
 
-    sched._pstep = spy
+    sched._step = spy
     shared = _prompt(cfg, 300, n=2 * PT)
     prompts = [np.concatenate([shared, _prompt(cfg, 301 + i, n=3 + i)])
                for i in range(3)]

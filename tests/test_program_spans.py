@@ -65,7 +65,7 @@ def traced(tmp_path_factory):
     eng.warmup(batch)
     params = m.init(jax.random.key(0))
     sched = DecodeScheduler(m, params, n_slots=2, cache_len=64,
-                            kv_page_tokens=PT, kv_max_seq=64)
+                            kv_page_tokens=PT)
     prompts = [r.integers(0, cfg.vocab_size, (n,)).astype(np.int32)
                for n in PROMPTS]
     for p in prompts:                    # compile outside the trace

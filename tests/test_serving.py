@@ -1,5 +1,7 @@
 """Serverless platform: cold/warm lifecycle, keep-alive eviction,
-trace generation, batched decode server."""
+trace generation, batched decode against full forwards."""
+import threading
+
 import numpy as np
 import jax
 import jax.numpy as jnp
@@ -7,7 +9,8 @@ import pytest
 
 from repro.models import transformer
 from repro.models.api import get_config
-from repro.serving.engine import (BatchedLMServer, FunctionInstance,
+from repro.serving.api import GenerateSpec
+from repro.serving.engine import (DecodeScheduler, FunctionInstance,
                                   ServerlessPlatform)
 from repro.serving.trace import Invocation, azure_like_trace, summarize
 from repro.store.store import WeightStore, deploy_model
@@ -105,16 +108,29 @@ def test_trace_generator_statistics():
 
 @pytest.mark.slow
 def test_batched_decode_matches_stepwise_forward():
-    """Greedy generation through the server == argmax over full forwards."""
+    """Two concurrent greedy generations through the scheduler, batched
+    over KV pages == argmax over repeated full forwards."""
     import dataclasses
     cfg = dataclasses.replace(get_config("smollm-360m", smoke=True),
                               compute_dtype=jnp.float32)
     m = transformer.build(cfg)
     params = m.init(jax.random.key(1))
-    srv = BatchedLMServer(m, params, cache_len=64)
+    sched = DecodeScheduler(m, params, n_slots=2, cache_len=64)
     prompt = jnp.asarray(np.random.default_rng(2).integers(
         0, cfg.vocab_size, (2, 8)), jnp.int32)
-    gen = srv.generate(prompt, n_new=5)
+    rows = [None, None]
+
+    def run(b):
+        rows[b] = sched.generate(GenerateSpec(prompt=prompt[b],
+                                              n_new=5)).tokens
+
+    threads = [threading.Thread(target=run, args=(b,)) for b in range(2)]
+    for t in threads:
+        t.start()
+    for t in threads:
+        t.join(timeout=300)
+        assert not t.is_alive()
+    gen = jnp.asarray(rows, jnp.int32)
     assert gen.shape == (2, 5)
     # oracle: greedy over repeated full forwards
     toks = prompt

@@ -195,7 +195,7 @@ def test_paged_programs_keep_the_pool_in_place(one_chip, monkeypatch,
     pools = place(lambda: m.init_kv_pages(pages, pt))
     fp = ops.registry.fingerprint()
     if program == "step":
-        lowered = decode._paged_step_fn(m, fp).lower(
+        lowered = decode._step_fn(m, fp).lower(
             place(lambda: m.init(jax.random.key(0))),
             place(lambda: m.init_cache_paged(B, 2560)), pools,
             arg((B, NP)), arg((B, 1)), arg((B,)), arg((B,), jnp.uint32),

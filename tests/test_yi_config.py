@@ -112,7 +112,7 @@ def test_runs_through_the_benchmark(yi_bench, monkeypatch, control):
     cell = spec.load_cell(CELL, benchmark=copy.deepcopy(b),
                           bench=str(bench_dir))
     assert cell.config["num_attention_heads"] == 8
-    for f in (decode._prefill_fn, decode._paged_step_fn, decode._pack_fn):
+    for f in (decode._prefill_fn, decode._step_fn, decode._pack_fn):
         f.cache_clear()
     cpu = peaks.Peaks(bf16_flops=1e12, int8_ops=1e12,
                       hbm_bytes_per_s=1e11, hbm_bytes=1e9)
